@@ -35,6 +35,7 @@ from benchmarks import (
     kernel_theta,
     theory_bounds,
 )
+from repro.utils.compile_cache import use_compile_cache
 
 BENCHES = {
     "fig1": fig1_burst.run,
@@ -314,6 +315,7 @@ def smoke() -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     if "--smoke" in sys.argv[1:]:
         t0 = time.time()
         smoke()

@@ -35,9 +35,11 @@ from repro.data import make_markov_task
 from repro.graphs import random_regular_graph
 from repro.models.model import Model
 from repro.optim import RwSgdPayload, adamw
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=64)
     ap.add_argument("--degree", type=int, default=8)

@@ -18,9 +18,11 @@ from repro.api import Experiment, ExperimentService
 from repro.core.failures import FailureConfig
 from repro.core.protocol import ProtocolConfig
 from repro.sweep import Scenario
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--store", default="env",
                     help="result-store dir ('env': honor $REPRO_RESULT_STORE)")

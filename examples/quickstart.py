@@ -17,9 +17,11 @@ from repro.data import make_markov_task, sample_batch
 from repro.launch.train import make_train_step
 from repro.models.model import Model
 from repro.optim import adamw, cosine_schedule
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=120)
     ap.add_argument("--batch", type=int, default=8)

@@ -12,6 +12,7 @@ import numpy as np
 from repro.api import Experiment
 from repro.core import FailureConfig, ProtocolConfig
 from repro.graphs import random_regular_graph
+from repro.utils.compile_cache import use_compile_cache
 
 
 def ascii_plot(z, z0, width=100, height=12, title=""):
@@ -31,6 +32,7 @@ def ascii_plot(z, z0, width=100, height=12, title=""):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale run")
     args = ap.parse_args()

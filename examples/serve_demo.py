@@ -17,9 +17,11 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.launch.serve import generate
 from repro.models.model import Model
+from repro.utils.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi_6b")
     ap.add_argument("--batch", type=int, default=4)

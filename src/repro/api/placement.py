@@ -13,6 +13,10 @@ named policy object the :class:`~repro.api.Plan` owns:
                          honored instead of silently running replicated.
   ``Placement.LOCAL``    never touch device placement.
 
+A placed sweep runs each device's block of scenarios under ``shard_map``
+(``core.simulator._split_scenarios``): the round's Mosaic kernel cannot
+be partitioned automatically, and scenarios never exchange data.
+
 Policies are tiny frozen values: pass one to ``Experiment(placement=...)``
 (strings ``"auto"`` / ``"sharded"`` / ``"local"`` also accepted).
 ``Placement.from_sharded`` maps the legacy tri-state — ``None`` -> AUTO,
@@ -84,13 +88,18 @@ class Placement:
 
     def place(self, pcfgs, fcfgs, n_scenarios: int):
         """Place stacked config leaves across the 'data' mesh axis per
-        this policy; returns the (possibly device_put) config pytrees.
+        this policy. Returns ``(pcfgs, fcfgs, mesh)``: the (possibly
+        device_put) config pytrees, and the mesh whose 'data' axis splits
+        the scenario axis — None when the scenarios stay where they are
+        (local policy, one device, or an auto policy whose scenario count
+        does not divide the devices). The sweep cores run each device's
+        scenario block under ``shard_map`` on that mesh.
         """
         if self.policy == "local":
-            return pcfgs, fcfgs
+            return pcfgs, fcfgs, None
         explicit = self.policy == "sharded"
-        if jax.device_count() == 1 and not explicit:
-            return pcfgs, fcfgs
+        if jax.device_count() == 1:
+            return pcfgs, fcfgs, None
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.launch.mesh import data_axis_size, make_local_mesh
@@ -103,7 +112,7 @@ class Placement:
                     f"divide the data axis ({data_axis_size(mesh)} devices); "
                     "pad the scenario list or use Placement.AUTO"
                 )
-            return pcfgs, fcfgs
+            return pcfgs, fcfgs, None
         sharding = NamedSharding(mesh, P("data"))
 
         def put(x):
@@ -112,6 +121,7 @@ class Placement:
         return (
             jax.tree_util.tree_map(put, pcfgs),
             jax.tree_util.tree_map(put, fcfgs),
+            mesh,
         )
 
 

@@ -55,6 +55,7 @@ _CORES = {
 }
 _MODE_STATICS = {
     mode: (_SEG_STATIC_ARGNAMES if mode.startswith("seg_") else _STATIC_ARGNAMES)
+    + (("mesh",) if mode.endswith("sweep") else ())
     for mode in _CORES
 }
 
@@ -316,14 +317,15 @@ class Plan:
 
     def _drive_segments(
         self, mode, sig, init_carry, cfg_args, segment_steps, time_axis,
-        store, skey,
+        store, skey, **statics,
     ):
         """Run one segmented trajectory/ensemble/sweep to completion.
 
         ``init_carry`` is a thunk (only called when no resumable snapshot
         exists); ``cfg_args`` is ``(pi, pcfg(s), fcfg(s))``;
         ``time_axis`` is where recorded chunks concatenate (run: 0,
-        ensemble: 1, sweep: 2). Snapshot writes are best-effort — a
+        ensemble: 1, sweep: 2); ``statics`` pass through to every chunk
+        (the sweep's scenario ``mesh``). Snapshot writes are best-effort — a
         failing store degrades to lost progress, never a failed run —
         and fault site ``segment.boundary`` fires after every boundary.
         """
@@ -345,7 +347,7 @@ class Plan:
             carry, chunk = executable(mode, seg_sig)(
                 carry, self.neighbors, self.degrees, self.mirror, *cfg_args,
                 seg_len=seg, steps=steps, n=self.n, payload=self.payload,
-                spec=self.spec, pspec=self.pspec,
+                spec=self.spec, pspec=self.pspec, **statics,
             )
             recorded = chunk if recorded is None else jax.tree_util.tree_map(
                 lambda a, b: jnp.concatenate((a, b), axis=time_axis),
@@ -486,13 +488,15 @@ class Plan:
                 return cached
 
         keys = jax.random.split(base, seeds)
-        pcfgs, fcfgs = self.placement.place(pcfgs, fcfgs, len(scenarios))
+        pcfgs, fcfgs, mesh = self.placement.place(
+            pcfgs, fcfgs, len(scenarios)
+        )
         if segment_steps is None:
             result = executable("sweep", sig)(
                 keys, self.neighbors, self.degrees, self.mirror,
                 self._pi(pcfg0), pcfgs, fcfgs,
                 steps=self.steps, n=self.n, payload=self.payload,
-                spec=self.spec, pspec=self.pspec,
+                spec=self.spec, pspec=self.pspec, mesh=mesh,
             )
         else:
             seg_sig = self._signature("seg_sweep", pcfg0, lens, fcfgs)
@@ -503,7 +507,7 @@ class Plan:
                     self.payload,
                 ),
                 (self._pi(pcfg0), pcfgs, fcfgs), segment_steps, 2,
-                store, skey,
+                store, skey, mesh=mesh,
             )
         if store is not None:
             store.put(

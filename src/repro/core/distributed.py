@@ -40,7 +40,7 @@ from repro.core import protocol as prt
 from repro.core import walkers as wlk
 from repro.core.walkers import WalkState
 from repro.graphs.state import availability_rows
-from repro.utils.compat import shard_map
+from jax import shard_map
 from repro.utils.prng import fold_in_time
 
 

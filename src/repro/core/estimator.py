@@ -225,9 +225,12 @@ def survival_node_sums_rows(
     hist_f = hist.astype(jnp.float32)  # exact: integer counts < 2**24
     total_f = total.astype(jnp.float32)
     valid = last_seen != NEVER
+    # unseen columns clamp to r=0, which no bin index (>= 0) is below, so
+    # the compare alone masks them: no (R, C, B) bool broadcast of
+    # ``valid``, which Mosaic cannot lay out
     r = jnp.where(valid, t - last_seen, 0)  # (R, C)
     bidx = jax.lax.broadcasted_iota(jnp.int32, (R, C, B), 2)
-    over = (r[:, :, None] > bidx) & valid[:, :, None]
+    over = r[:, :, None] > bidx
     cnt = jnp.sum(over.astype(jnp.float32), axis=1)  # (R, B)
     mass = jnp.sum(cnt * hist_f, axis=1)
     n_valid = jnp.sum(valid.astype(jnp.float32), axis=1)

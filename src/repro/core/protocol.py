@@ -91,8 +91,9 @@ class ProtocolConfig:
     analytic_survival: bool = False  # footnote 5: geometric survival from pi
     # 'gather' (row-restricted cumsum+gather) | 'compare' (dense compare-
     # accumulate) | 'pallas' (theta_survival kernel) | 'fused' (one
-    # round_update pass: scatter+max+sums) | 'auto' (best per backend)
-    estimator_impl: str = "gather"
+    # round_update pass: scatter+max+sums) | 'auto' (best per backend:
+    # gather on CPU/GPU, fused on TPU, where the whole-round kernel runs)
+    estimator_impl: str = "auto"
     # ---- beyond-paper: self-calibrating thresholds ----------------------
     # The paper hand-tunes eps per graph (Fig. 4 uses eps in {1.85,2,2.1})
     # and its Irwin-Hall rule ignores the inspection-paradox bias

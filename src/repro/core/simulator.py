@@ -944,24 +944,62 @@ def _run_ensemble_core(
     )(keys)
 
 
+def _split_scenarios(mesh, fn, stacked, shared):
+    """``fn(*stacked, *shared)``, whose ``stacked`` arguments and outputs
+    all lead with the scenario axis. With a ``mesh`` (from
+    ``Placement.place``) each device runs its block of scenarios under
+    ``shard_map`` over the 'data' axis: a Mosaic kernel in the round
+    cannot be partitioned automatically, and no scenario needs another's
+    data. ``shared`` arguments are replicated."""
+    if mesh is None:
+        return fn(*stacked, *shared)
+    from jax.sharding import PartitionSpec as P
+
+    # no collectives: per-device blocks never mix, so the scan carries'
+    # varying-axis types need no checking
+    specs = (P("data"),) * len(stacked) + (P(),) * len(shared)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=specs, out_specs=P("data"), check_vma=False
+    )(*stacked, *shared)
+
+
+def _vmap_scenarios(fn, *stacked):
+    """``jax.vmap(fn)`` over the leading scenario axis of ``stacked``.
+
+    A one-row stack runs ``fn`` unbatched on its row and re-adds the axis:
+    that is the ensemble's own program, so a one-scenario sweep row is
+    bitwise its ``ensemble`` even where XLA rounds a batch-of-one matmul
+    differently from the unbatched one (it does on CPU for payload
+    models)."""
+    if jax.tree_util.tree_leaves(stacked)[0].shape[0] == 1:
+        row = jax.tree_util.tree_map(lambda x: x[0], stacked)
+        return jax.tree_util.tree_map(lambda x: x[None], fn(*row))
+    return jax.vmap(fn)(*stacked)
+
+
 def _sweep_core(
     keys, neighbors, degrees, mirror, pi, pcfgs, fcfgs, steps, n,
-    payload=None, spec=SCALARS, pspec=None,
+    payload=None, spec=SCALARS, pspec=None, mesh=None,
 ):
     """Stacked configs (leaves with leading (S,) axis) + (seeds,) keys ->
     RecordedOutputs with leading (S, seeds) axes, all in one XLA program
     (a (RecordedOutputs, payload_outputs) pair when a payload is
-    attached)."""
+    attached). ``mesh`` splits the scenario axis (``_split_scenarios``)."""
 
-    def one_scenario(pcfg, fcfg):
-        return jax.vmap(
-            lambda k: _run_core(
-                k, neighbors, degrees, mirror, pi, pcfg, fcfg, steps, n,
-                payload, spec, pspec,
-            )[1]
-        )(keys)
+    def scenarios(pcfgs, fcfgs, keys, neighbors, degrees, mirror, pi):
+        def one_scenario(pcfg, fcfg):
+            return jax.vmap(
+                lambda k: _run_core(
+                    k, neighbors, degrees, mirror, pi, pcfg, fcfg, steps, n,
+                    payload, spec, pspec,
+                )[1]
+            )(keys)
 
-    return jax.vmap(one_scenario)(pcfgs, fcfgs)
+        return _vmap_scenarios(one_scenario, pcfgs, fcfgs)
+
+    return _split_scenarios(
+        mesh, scenarios, (pcfgs, fcfgs), (keys, neighbors, degrees, mirror, pi)
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1016,19 +1054,25 @@ def _seg_ensemble_core(
 
 def _seg_sweep_core(
     carry, neighbors, degrees, mirror, pi, pcfgs, fcfgs, seg_len, steps, n,
-    payload=None, spec=SCALARS, pspec=None,
+    payload=None, spec=SCALARS, pspec=None, mesh=None,
 ):
-    """One segment of a stacked sweep (carry leaves lead with (S, seeds))."""
+    """One segment of a stacked sweep (carry leaves lead with (S, seeds));
+    ``mesh`` splits the scenario axis (``_split_scenarios``)."""
 
-    def one_scenario(c, pcfg, fcfg):
-        return jax.vmap(
-            lambda cc: _seg_run_core(
-                cc, neighbors, degrees, mirror, pi, pcfg, fcfg, seg_len,
-                steps, n, payload, spec, pspec,
-            )
-        )(c)
+    def scenarios(carry, pcfgs, fcfgs, neighbors, degrees, mirror, pi):
+        def one_scenario(c, pcfg, fcfg):
+            return jax.vmap(
+                lambda cc: _seg_run_core(
+                    cc, neighbors, degrees, mirror, pi, pcfg, fcfg, seg_len,
+                    steps, n, payload, spec, pspec,
+                )
+            )(c)
 
-    return jax.vmap(one_scenario)(carry, pcfgs, fcfgs)
+        return _vmap_scenarios(one_scenario, carry, pcfgs, fcfgs)
+
+    return _split_scenarios(
+        mesh, scenarios, (carry, pcfgs, fcfgs), (neighbors, degrees, mirror, pi)
+    )
 
 
 def _init_ensemble_carry(keys, neighbors, pcfg, fcfg, steps, n, payload=None):

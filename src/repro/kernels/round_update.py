@@ -110,6 +110,21 @@ def round_update_ref(last_seen, hist, total, pos, track, r, valid, upd, t):
     return ls, rts.hist, rts.total, sums
 
 
+def _scatter_max_rows(hit, track, upd, C):
+    """(bn, C) tile of the scatter-max ``[pos[j], track[j]] <- upd[j]``:
+    row i, column c takes the max ``upd[j]`` over the walks j with
+    ``hit[i, j]`` and ``track[j] == c`` (NEVER where none). The (bn, W, C)
+    select runs on an int32 mask: Mosaic cannot lay out the 3-D
+    broadcast of a bool."""
+    W = hit.shape[1]
+    col_iota = jax.lax.broadcasted_iota(jnp.int32, (W, C), 1)
+    m = jnp.where(
+        col_iota == jnp.reshape(track, (W, 1)), jnp.reshape(upd, (W, 1)), NEVER
+    )  # (W, C)
+    hit3 = hit.astype(jnp.int32)[:, :, None]
+    return jnp.max(jnp.where(hit3 > 0, m[None, :, :], NEVER), axis=1)
+
+
 def _round_kernel(
     t_ref, pos_ref, track_ref, rbin_ref, w_ref, upd_ref,
     ls_ref, hist_ref, tot_ref,
@@ -144,12 +159,7 @@ def _round_kernel(
     ).astype(tot.dtype)
 
     # 2. last-seen scatter-max at (pos[j], track[j]) <- upd[j]
-    col_iota = jax.lax.broadcasted_iota(jnp.int32, (W, C), 1)
-    m = jnp.where(col_iota == track[:, None], upd[:, None], NEVER)  # (W, C)
-    upd_rows = jnp.max(
-        jnp.where(hit[:, :, None], m[None, :, :], NEVER), axis=1
-    )  # (bn, C)
-    ls = jnp.maximum(ls, upd_rows)
+    ls = jnp.maximum(ls, _scatter_max_rows(hit, track, upd, C))
 
     # 3. theta sums on the updated tile: the shared compare-accumulate
     #    core (estimator.survival_node_sums_rows), VMEM-resident
@@ -263,6 +273,20 @@ def round_update(
 # ---------------------------------------------------------------------------
 
 
+def _take(col, idx):
+    """``col[idx]`` for an (N, 1) int32 column and (1, W) indices, as a
+    one-hot compare-and-reduce (Mosaic lowers no vector gather). An index
+    outside [0, N) reads 0. Values must be >= 0 (masks, counts)."""
+    oh = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0], idx.shape[1]), 0)
+    return jnp.max(jnp.where(oh == idx, col, 0), axis=0, keepdims=True)
+
+
+def _select(c, a, b):
+    """``jnp.where(c, a, b)`` for bool ``a``/``b``: Mosaic cannot lower a
+    select between bool vectors."""
+    return (c & a) | (~c & b)
+
+
 def _whole_round_kernel(
     decafork_plus,
     # broadcast scalars
@@ -270,10 +294,10 @@ def _whole_round_kernel(
     #                            eps, eps2, fork_prob (start-gates folded in)
     params_i_ref,  # (1, 4) i32: t, byz_kill_node, pacman_node, enabled
     # walk-level inputs (broadcast to every tile)
-    pos_ref, track_ref, act_ref,  # (1, W) i32 / i32 / bool
+    pos_ref, track_ref, act_ref,  # (1, W) i32 / i32 / i32 0-1 mask
     u_move_ref, u_pfail_ref, u_fork_ref, u_term_ref,  # (1, W) f32
     deg_ref,  # (1, W) i32 degrees at the walks' pre-hop nodes
-    nbrw_ref, eupw_ref, efw_ref, erw_ref,  # (W, D) walk-row adjacency/masks
+    nbrw_ref, eupw_ref, efw_ref, erw_ref,  # (D, W) walk-row adjacency/masks
     uburst_ref,  # (K', W) f32 per-burst score uniforms
     bsz_ref,  # (1, K') i32 effective burst sizes (0 when not firing)
     # node-level inputs
@@ -282,11 +306,11 @@ def _whole_round_kernel(
     ls_ref, hist_ref, tot_ref,  # (bn, C) i32 / (bn, B) i16 / (bn, 1) i32
     # outputs
     ls_out, hist_out, tot_out,  # updated observation tiles
-    eup_out,  # (bn, D) updated edge tile
-    nodeup_out,  # (1, N) updated node mask (constant block)
+    eup_out,  # (bn, D) updated edge tile (i32 0-1)
+    nodeup_out,  # (1, N) updated node mask (i32 0-1, constant block)
     pos_out, act_out,  # (1, W) post-hop / post-failure walk state
     theta_out,  # (1, W) f32 theta-hat accumulator -> final theta
-    chosen_out, fork_out, term_out,  # (1, W) bool decision masks
+    chosen_out, fork_out, term_out,  # (1, W) i32 0-1 decision masks
 ):
     """Two-phase whole-round pass; grid = (2, num_tiles), phase-major.
 
@@ -301,32 +325,32 @@ def _whole_round_kernel(
     walk state and theta accumulator between phases. Tile-mapped outputs
     are written in BOTH phases (topology recomputed, observation tiles
     passed through in phase 0) so no revisited block holds stale data.
+
+    Walk vectors stay ``(1, W)`` rows; a value indexed by walk is read
+    with a one-hot compare-and-reduce (``_take``) against an ``(N, 1)``
+    or ``(C, 1)`` column, and walk-pair comparisons pair a row with its
+    transpose. Scalars stay ``(1, 1)`` blocks that broadcast. Masks cross
+    the kernel boundary as int32 0/1: Mosaic cannot load narrow bool
+    tiles such as the ``(bn, D)`` edge block.
     """
     ph = pl.program_id(0)
     i = pl.program_id(1)
-    pf = params_f_ref[0, :]
-    pint = params_i_ref[0, :]
-    t = pint[0]
-    byz_node = pint[1]
-    pac_node = pint[2]
-    enabled = pint[3] > 0
-    p_fail, p_nfail, p_lfail = pf[0], pf[1], pf[2]
-    p_nrec, p_lrec, eps, eps2, p_fork = pf[3], pf[4], pf[5], pf[6], pf[7]
+    pf = params_f_ref[...]
+    pint = params_i_ref[...]
+    t = pint[:, 0:1]
+    byz_node = pint[:, 1:2]
+    pac_node = pint[:, 2:3]
+    enabled = pint[:, 3:4] > 0
+    p_fail, p_nfail, p_lfail = pf[:, 0:1], pf[:, 1:2], pf[:, 2:3]
+    p_nrec, p_lrec, eps = pf[:, 3:4], pf[:, 4:5], pf[:, 5:6]
+    eps2, p_fork = pf[:, 6:7], pf[:, 7:8]
 
     # -- edge-tile topology update, recomputed in both phases so every
     #    mapped output block is written on every grid step
-    eup = eup_ref[...]
+    eup = eup_ref[...] != 0
     fail = ef_ref[...] < p_lfail
     rec = er_ref[...] < p_lrec
-    eup_out[...] = jnp.where(eup, ~fail, rec)
-
-    # the full updated node mask (cheap (N,) elementwise; the epilogue
-    # needs it for kills and for BOTH hop endpoints)
-    node_up = nodeup_ref[0, :]
-    crash = unfail_ref[0, :] < p_nfail
-    recov = unrec_ref[0, :] < p_nrec
-    sched = sched_ref[0, :]
-    node_new = jnp.where(node_up, ~(crash | sched), recov & ~sched)
+    eup_out[...] = _select(eup, ~fail, rec).astype(jnp.int32)
 
     @pl.when(ph == 0)
     def _pass_through_obs():
@@ -336,103 +360,133 @@ def _whole_round_kernel(
 
     @pl.when((ph == 0) & (i == 0))
     def _walk_epilogue():
-        nodeup_out[...] = node_new[None, :]
-        pos = pos_ref[0, :]
-        active = act_ref[0, :]
+        # the full updated node mask (the epilogue needs it for kills
+        # and for BOTH hop endpoints)
+        node_up = nodeup_ref[...] != 0
+        crash = unfail_ref[...] < p_nfail
+        recov = unrec_ref[...] < p_nrec
+        sched = sched_ref[...] != 0
+        node_new = _select(node_up, ~(crash | sched), recov & ~sched)
+        node_new = node_new.astype(jnp.int32)
+        nodeup_out[...] = node_new
+        node_col = node_new.T  # (N, 1)
+        pos = pos_ref[...]
         # resident kills at the pre-hop positions
-        active = active & node_new[pos]
-        # masked rank-select hop over the walks' own adjacency rows
+        up_at_pos = _take(node_col, pos) > 0
+        active = (act_ref[...] != 0) & up_at_pos
+        # masked rank-select hop over the walks' own adjacency rows:
+        # walkers.select_available_edge, one edge slot d at a time
         nbr = nbrw_ref[...]
-        fail_w = efw_ref[...] < p_lfail
-        rec_w = erw_ref[...] < p_lrec
-        eup_new_w = jnp.where(eupw_ref[...], ~fail_w, rec_w)
-        deg = deg_ref[0, :]
-        within = (
-            jax.lax.broadcasted_iota(jnp.int32, nbr.shape, 1) < deg[:, None]
+        eup_w = _select(
+            eupw_ref[...] != 0, ~(efw_ref[...] < p_lfail), erw_ref[...] < p_lrec
         )
-        avail = within & eup_new_w & node_new[pos][:, None] & node_new[nbr]
-        adeg, sel = wlk.select_available_edge(
-            avail, u_move_ref[0, :], jnp.int32
-        )
-        nxt = jnp.take_along_axis(nbr, sel[:, None], axis=1)[:, 0]
+        deg = deg_ref[...]
+        D = nbr.shape[0]
+        avail = [
+            (d < deg)
+            & eup_w[d:d + 1, :]
+            & up_at_pos
+            & (_take(node_col, nbr[d:d + 1, :]) > 0)
+            for d in range(D)
+        ]
+        adeg = sum(a.astype(jnp.int32) for a in avail)
+        idx = jnp.minimum((u_move_ref[...] * adeg).astype(jnp.int32), adeg - 1)
+        rank = jnp.full_like(adeg, -1)
+        nxt = pos
+        for d in range(D):
+            rank = rank + avail[d].astype(jnp.int32)
+            nxt = jnp.where(avail[d] & (rank == idx), nbr[d:d + 1, :], nxt)
         pos = jnp.where(active & (adeg > 0), nxt, pos)
         # walk-level threat models: probabilistic, bursts, Byz, Pac-Man
-        active = active & ~(u_pfail_ref[0, :] < p_fail)
+        active = active & ~(u_pfail_ref[...] < p_fail)
         for b in range(uburst_ref.shape[0]):
-            score = jnp.where(active, uburst_ref[b, :], jnp.inf)
-            rank = jnp.sum(score[:, None] > score[None, :], axis=1)
-            active = active & ~(rank < bsz_ref[0, b])
+            score = jnp.where(active, uburst_ref[b:b + 1, :], jnp.inf)
+            # rank[j] = #{k : score[j] > score[k]}
+            rank = jnp.sum(
+                (score.T < score).astype(jnp.int32), axis=0, keepdims=True
+            )
+            active = active & ~(rank < bsz_ref[:, b:b + 1])
         active = active & ~(pos == byz_node)  # -1 sentinels never match
         active = active & ~(pos == pac_node)
-        pos_out[...] = pos[None, :]
-        act_out[...] = active[None, :]
+        pos_out[...] = pos
+        act_out[...] = active.astype(jnp.int32)
 
     @pl.when(ph == 1)
     def _observe_and_decide():
-        pos = pos_out[0, :]
-        active = act_out[0, :]
-        track = track_ref[0, :]
+        pos = pos_out[...]
+        active = act_out[...] != 0
+        track = track_ref[...]
         ls = ls_ref[...]
         hist = hist_ref[...]
         tot = tot_ref[...]
         bn, C = ls.shape
         B = hist.shape[1]
-        W = pos.shape[0]
+        W = pos.shape[1]
         base = i * bn
         rows = jax.lax.broadcasted_iota(jnp.int32, (bn, W), 0) + base
-        hit = rows == pos[None, :]
+        hit = rows == pos
         # prev = last_seen[pos, track]: a walk's row lives in exactly one
         # tile, so the masked max over this tile IS the gather for the
         # walks that land here (others see NEVER -> no contribution)
-        ls_track = jnp.take(ls, track, axis=1)  # (bn, W)
-        prev = jnp.max(jnp.where(hit, ls_track, NEVER), axis=0)
+        c_iota = jax.lax.broadcasted_iota(jnp.int32, (bn, C, W), 1)
+        ls_track = jnp.max(
+            jnp.where(c_iota == track[None, :, :], ls[:, :, None], NEVER),
+            axis=1,
+        )  # (bn, W) = ls[:, track]
+        prev = jnp.max(
+            jnp.where(hit, ls_track, NEVER), axis=0, keepdims=True
+        )
         r = t - prev
         valid = active & (prev != NEVER) & (r >= 1)
         rbin = jnp.clip(r, 1, B) - 1
         w8 = valid.astype(jnp.float32)
         bin_iota = jax.lax.broadcasted_iota(jnp.int32, (W, B), 1)
-        ev = jnp.where(bin_iota == rbin[:, None], w8[:, None], 0.0)
+        ev = jnp.where(bin_iota == rbin.T, w8.T, 0.0)  # (W, B)
         hist = hist + jnp.dot(hit.astype(jnp.float32), ev).astype(hist.dtype)
         tot = tot + jnp.sum(
-            jnp.where(hit, w8[None, :], 0.0), axis=1, keepdims=True
+            jnp.where(hit, w8, 0.0), axis=1, keepdims=True
         ).astype(tot.dtype)
         upd = jnp.where(active, t, NEVER)
-        col_iota = jax.lax.broadcasted_iota(jnp.int32, (W, C), 1)
-        m = jnp.where(col_iota == track[:, None], upd[:, None], NEVER)
-        upd_rows = jnp.max(
-            jnp.where(hit[:, :, None], m[None, :, :], NEVER), axis=1
-        )
-        ls = jnp.maximum(ls, upd_rows)
+        ls = jnp.maximum(ls, _scatter_max_rows(hit, track, upd, C))
         ls_out[...] = ls
         hist_out[...] = hist
         tot_out[...] = tot
         # per-walk theta contribution from this tile's node sums
         sums = est.survival_node_sums_rows(ls, hist, tot[:, 0], t)
-        contrib = jnp.sum(jnp.where(hit, sums[:, None], 0.0), axis=0)
-        acc = jnp.where(i == 0, contrib, theta_out[0, :] + contrib)
-        theta_out[...] = acc[None, :]
+        contrib = jnp.sum(
+            jnp.where(hit, jnp.reshape(sums, (bn, 1)), 0.0),
+            axis=0,
+            keepdims=True,
+        )
+        acc = jnp.where(i == 0, contrib, theta_out[...] + contrib)
+        theta_out[...] = acc
 
         @pl.when(i == pl.num_programs(1) - 1)
         def _decide():
             theta = acc - 0.5  # theta_hat_from_node_sums
-            theta_out[...] = theta[None, :]
-            chosen = prt.choose_walks_pairwise(pos, active)
-            fork = (
-                chosen & (theta < eps) & (u_fork_ref[0, :] < p_fork) & enabled
+            theta_out[...] = theta
+            # protocol.choose_walks_pairwise: each walk takes the min
+            # candidate slot over the walks sharing its node
+            slots = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+            cand = jnp.where(active, slots, W)
+            best = jnp.min(
+                jnp.where(pos.T == pos, cand.T, W), axis=0, keepdims=True
             )
+            chosen = active & (best == slots)
+            fork = chosen & (theta < eps) & (u_fork_ref[...] < p_fork) & enabled
             if decafork_plus:
                 term = (
                     chosen
                     & (theta > eps2)
-                    & (u_term_ref[0, :] < p_fork)
+                    & (u_term_ref[...] < p_fork)
                     & enabled
                 )
                 term = term & ~fork
             else:
                 term = jnp.zeros_like(fork)
-            chosen_out[...] = chosen[None, :]
-            fork_out[...] = fork[None, :]
-            term_out[...] = term[None, :]
+            chosen_out[...] = chosen.astype(jnp.int32)
+            fork_out[...] = fork.astype(jnp.int32)
+            term_out[...] = term.astype(jnp.int32)
 
 
 @functools.partial(
@@ -512,7 +566,7 @@ def whole_round_pallas(
         e_rec = jnp.concatenate([e_rec, jnp.ones((pad, D), e_rec.dtype)])
     npad = n + pad
     walk_spec = pl.BlockSpec((1, W), lambda p, i: (0, 0))
-    wd_spec = pl.BlockSpec((W, D), lambda p, i: (0, 0))
+    wd_spec = pl.BlockSpec((D, W), lambda p, i: (0, 0))
     node_full_spec = pl.BlockSpec((1, npad), lambda p, i: (0, 0))
     edge_tile_spec = pl.BlockSpec((bn, D), lambda p, i: (i, 0))
     outs = pl.pallas_call(
@@ -563,14 +617,14 @@ def whole_round_pallas(
             jax.ShapeDtypeStruct((npad, C), last_seen.dtype),
             jax.ShapeDtypeStruct((npad, B), hist.dtype),
             jax.ShapeDtypeStruct((npad, 1), total.dtype),
-            jax.ShapeDtypeStruct((npad, D), jnp.bool_),
-            jax.ShapeDtypeStruct((1, npad), jnp.bool_),
+            jax.ShapeDtypeStruct((npad, D), jnp.int32),
+            jax.ShapeDtypeStruct((1, npad), jnp.int32),
             jax.ShapeDtypeStruct((1, W), pos.dtype),
-            jax.ShapeDtypeStruct((1, W), jnp.bool_),
+            jax.ShapeDtypeStruct((1, W), jnp.int32),
             jax.ShapeDtypeStruct((1, W), jnp.float32),
-            jax.ShapeDtypeStruct((1, W), jnp.bool_),
-            jax.ShapeDtypeStruct((1, W), jnp.bool_),
-            jax.ShapeDtypeStruct((1, W), jnp.bool_),
+            jax.ShapeDtypeStruct((1, W), jnp.int32),
+            jax.ShapeDtypeStruct((1, W), jnp.int32),
+            jax.ShapeDtypeStruct((1, W), jnp.int32),
         ],
         interpret=interpret,
     )(
@@ -578,23 +632,23 @@ def whole_round_pallas(
         params_i,
         pos[None, :],
         track[None, :],
-        active[None, :],
+        active.astype(jnp.int32)[None, :],
         u_move[None, :],
         u_pfail[None, :],
         u_fork[None, :],
         u_term[None, :],
         degrees_rows.astype(jnp.int32)[None, :],
-        neighbors_rows,
-        edge_up_rows,
-        e_fail_rows.astype(jnp.float32),
-        e_rec_rows.astype(jnp.float32),
+        neighbors_rows.astype(jnp.int32).T,
+        edge_up_rows.astype(jnp.int32).T,
+        e_fail_rows.astype(jnp.float32).T,
+        e_rec_rows.astype(jnp.float32).T,
         u_burst,
         burst_sizes_eff[None, :],
-        node_up[None, :],
+        node_up.astype(jnp.int32)[None, :],
         u_nfail[None, :],
         u_nrec[None, :],
-        sched_down[None, :],
-        edge_up,
+        sched_down.astype(jnp.int32)[None, :],
+        edge_up.astype(jnp.int32),
         e_fail,
         e_rec,
         last_seen,
@@ -607,12 +661,12 @@ def whole_round_pallas(
         ls_o[:n],
         hist_o[:n],
         tot_o[:n, 0],
-        nup_o[0, :n],
-        eup_o[:n],
+        nup_o[0, :n] != 0,
+        eup_o[:n] != 0,
         pos_o[0],
-        act_o[0],
+        act_o[0] != 0,
         theta_o[0],
-        chosen_o[0],
-        fork_o[0],
-        term_o[0],
+        chosen_o[0] != 0,
+        fork_o[0] != 0,
+        term_o[0] != 0,
     )

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh).
 
 For each combination this produces, with ZERO device allocation
@@ -25,6 +22,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -382,6 +380,14 @@ def run_protocol(multi_pod: bool, out_dir: str, force: bool = False):
 
 
 def main():
+    # 512 host-platform devices for the production meshes; appended to any
+    # flags already set, and only before jax first initializes a backend
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in (
+            os.environ.get("XLA_FLAGS", ""),
+            "--xla_force_host_platform_device_count=512",
+        ) if f
+    )
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

@@ -2,16 +2,12 @@
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state — the dry-run must set XLA_FLAGS before the first jax init.
-
-Mesh construction goes through ``repro.utils.compat.make_mesh`` so the
-``axis_types`` kwarg (jax >= 0.5) degrades gracefully on the installed
-jax 0.4.x (see the compat module for the version policy).
 """
 from __future__ import annotations
 
 import jax
-
-from repro.utils.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -33,7 +33,7 @@ def maybe_shard_scenarios(pcfgs, fcfgs, n_scenarios: int, *, explicit: bool = Fa
     from repro.api import Placement
 
     policy = Placement.SHARDED if explicit else Placement.AUTO
-    return policy.place(pcfgs, fcfgs, n_scenarios)
+    return policy.place(pcfgs, fcfgs, n_scenarios)[:2]
 
 
 def run_scenarios(

@@ -1,8 +1,10 @@
 import os
 import sys
 
-# src-layout import path (mirrors PYTHONPATH=src)
+# src-layout import path (mirrors PYTHONPATH=src), plus the repo root for
+# the benchmark configs (mirrors running from the root)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+sys.path.insert(1, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax  # noqa: E402
 

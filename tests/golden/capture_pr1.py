@@ -1,11 +1,19 @@
 """Capture golden PR-1 trajectories for the topology no-op equivalence tests.
 
-Run at the pre-GraphState commit to (re)generate
-``tests/golden/pr1_trajectories.json``; ``tests/test_topology.py`` then
-asserts that the refactored simulator with every topology-failure knob
-disabled reproduces these outputs bitwise.
+Writes ``tests/golden/pr1_trajectories.json``; ``tests/test_topology.py``
+then asserts that the simulator with every topology-failure knob disabled
+reproduces these outputs bitwise (as do the payload and zoo neutrality
+tests).
 
     PYTHONPATH=src python tests/golden/capture_pr1.py
+
+The trajectories depend on the toolchain as well as on the code: the
+PRNG bits follow ``jax_threefry_partitionable`` (its default flipped to
+True in jax 0.5), and float reductions follow XLA's CPU codegen (a
+theta_mean can move by an ulp when the order of a sum changes). The JSON
+records the jax/jaxlib versions and PRNG settings it was captured under
+in its ``toolchain`` entry; re-capture only when a toolchain change, not
+a code change, moved the trajectories.
 """
 from __future__ import annotations
 
@@ -14,8 +22,11 @@ import os
 
 import numpy as np
 
-from repro.core import FailureConfig, ProtocolConfig, run_ensemble
-from repro.core.simulator import run_sweep
+import jax
+import jaxlib
+
+from repro.api import Experiment
+from repro.core import FailureConfig, ProtocolConfig
 from repro.graphs import random_regular_graph
 
 OUT = os.path.join(os.path.dirname(__file__), "pr1_trajectories.json")
@@ -53,12 +64,26 @@ def _outputs_to_dict(outs) -> dict:
     }
 
 
+def toolchain() -> dict:
+    return {
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "jax_threefry_partitionable": bool(
+            jax.config.jax_threefry_partitionable
+        ),
+        "jax_default_prng_impl": str(jax.config.jax_default_prng_impl),
+        "backend": jax.default_backend(),
+    }
+
+
 def main() -> None:
     graph = random_regular_graph(N, DEG, seed=GRAPH_SEED)
-    payload = {"ensemble": {}, "sweep": {}}
+    payload = {"toolchain": toolchain(), "ensemble": {}, "sweep": {}}
     for name, pcfg, fcfg in cases():
-        outs = run_ensemble(graph, pcfg, fcfg, steps=STEPS, seeds=SEEDS,
-                            base_key=BASE_KEY, outputs="full")
+        outs = Experiment(
+            graph=graph, protocol=pcfg, failures=fcfg, steps=STEPS,
+            outputs="full",
+        ).ensemble(SEEDS, base_key=BASE_KEY)
         payload["ensemble"][name] = _outputs_to_dict(outs)
 
     sweep_cases = [
@@ -66,8 +91,9 @@ def main() -> None:
         for e, f in zip((1.4, 2.2), (FailureConfig(burst_times=(20,), burst_sizes=(2,)),
                                      FailureConfig(burst_times=(30,), burst_sizes=(1,), p_fail=0.002)))
     ]
-    outs = run_sweep(graph, sweep_cases, steps=STEPS, seeds=SEEDS,
-                     base_key=BASE_KEY, outputs="full")
+    outs = Experiment(
+        graph=graph, scenarios=sweep_cases, steps=STEPS, outputs="full",
+    ).plan().sweep_stacked(seeds=SEEDS, base_key=BASE_KEY)
     payload["sweep"]["decafork/eps-grid"] = _outputs_to_dict(outs)
 
     with open(OUT, "w") as f:

@@ -8,7 +8,8 @@ from repro.core import walkers as wlk
 from repro.core.distributed import make_sharded_step
 from repro.core.protocol import ProtocolConfig
 from repro.graphs import GraphState, availability, random_regular_graph
-from repro.utils.compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 from repro.utils.prng import fold_in_time
 
 

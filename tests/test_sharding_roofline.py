@@ -3,9 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
-
-from repro.utils.compat import AxisType, abstract_mesh  # noqa: F401  (compat-gated)
 
 from repro.launch import roofline as rl
 from repro.launch import sharding as shd
@@ -15,7 +14,7 @@ from repro.launch import sharding as shd
 def mesh():
     # abstract mesh: sharding specs only need axis sizes, so build a
     # 1-device-backed mesh with logical sizes via AbstractMesh semantics.
-    return abstract_mesh((16, 16), ("data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_spec_divisibility_fallback(mesh):

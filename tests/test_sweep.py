@@ -214,6 +214,31 @@ def test_placement_dispatch(graph, monkeypatch):
         Experiment(graph=graph, scenarios=scenarios, steps=5, placement=7)
 
 
+def test_scenario_blocks_under_shard_map_are_bitwise(graph):
+    """A sweep whose scenario axis is split over a 'data' mesh — each
+    device's block under shard_map, as a placed sweep runs on several
+    chips — is bitwise the unsplit sweep (one block on this device)."""
+    from repro.core import simulator as sim
+    from repro.launch.mesh import make_local_mesh
+
+    scenarios = [
+        (_pcfg("decafork", "gather", eps=e), f)
+        for e, f in zip((1.6, 2.0, 2.4), _fcfgs())
+    ]
+    pcfgs, fcfgs = stack_configs(scenarios)
+    plan = Experiment(graph=graph, scenarios=scenarios, steps=STEPS).plan()
+    keys = jax.random.split(jax.random.key(5), SEEDS)
+    core = jax.jit(
+        sim._sweep_core, static_argnames=("steps", "n", "payload", "spec",
+                                          "pspec", "mesh"),
+    )
+    args = (keys, plan.neighbors, plan.degrees, plan.mirror, None, pcfgs,
+            fcfgs)
+    got = core(*args, steps=STEPS, n=graph.n, mesh=make_local_mesh())
+    want = core(*args, steps=STEPS, n=graph.n)
+    _assert_outputs_equal(want, got, "shard_map blocks")
+
+
 def test_placement_from_legacy_tristate():
     """Placement.from_sharded maps the legacy tri-state by identity:
     bool-equal ints must not silently alias into the wrong policy."""
