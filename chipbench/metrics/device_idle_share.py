@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of device op intervals / window), in %, mean over the chips.
+One reader for each split of the metric (``device_idle_share.<split>``)."""
+
+
+def read(rec):
+    if rec.trace is None or not rec.trace.window_s:
+        return None
+    return 100.0 * (1.0 - rec.trace.busy_s / rec.trace.window_s)
