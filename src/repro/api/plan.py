@@ -15,6 +15,14 @@ runners used to split between themselves and their callers:
   3. **the placement decision** — ``Placement`` applied to the stacked
      scenario leaves at exactly one point.
 
+Each public call (``run``, ``ensemble``, ``sweep_stacked``, ``sweep``)
+opens a host span on the profiler's clock, ``plan.<call>``, with two
+children: ``plan.prepare`` (keys, signature, executable lookup) and
+``plan.enqueue`` (the executable call, any trace or compile included).
+While a profiler session runs, the span carries the seed count, a short
+digest of the static signature and how many cache slots and XLA compiles
+the call added; with no session the spans cost next to nothing.
+
 The executables are jitted wrappers over the three un-jitted cores in
 ``core/simulator.py`` (one trajectory / vmap over seeds / vmap over
 (scenario, seed)); everything traces through the same ``_run_core``, so
@@ -23,6 +31,7 @@ The executables are jitted wrappers over the three un-jitted cores in
 """
 from __future__ import annotations
 
+import hashlib
 from typing import Sequence, Tuple
 
 import jax
@@ -178,6 +187,45 @@ def clear_cache() -> None:
     _JITTED.clear()
 
 
+class _CallSpan:
+    """The host span of one public Plan call (see module docstring).
+
+    ``signature`` names the call's static signature once it is known;
+    the attributes are computed, and the cache counters read, only while
+    a profiler session runs."""
+
+    def __init__(self, call: str, seeds: int):
+        self._span = jax.profiler.TraceAnnotation(f"plan.{call}")
+        self._seeds = int(seeds)
+        self._signature = None
+        self._before = None
+
+    def __enter__(self):
+        if jax.profiler.TraceAnnotation.is_enabled():
+            stats = cache_stats()
+            self._before = (stats["entries"], stats["xla_compiles"])
+        self._span.__enter__()
+        return self
+
+    def signature(self, sig: tuple) -> None:
+        self._signature = sig
+
+    def __exit__(self, *exc):
+        if self._before is not None:
+            stats = cache_stats()
+            meta = dict(
+                seeds=self._seeds,
+                new_slots=stats["entries"] - self._before[0],
+                compiled=stats["xla_compiles"] - self._before[1],
+            )
+            if self._signature is not None:
+                meta["signature"] = hashlib.blake2b(
+                    repr(self._signature).encode(), digest_size=4
+                ).hexdigest()
+            self._span.set_metadata(**meta)
+        return self._span.__exit__(*exc)
+
+
 def _as_key(key) -> jax.Array:
     return jax.random.key(key) if isinstance(key, int) else key
 
@@ -274,26 +322,38 @@ class Plan:
         """One trajectory; returns ``(final SimState, RecordedOutputs)``
         (with a payload: ``((state, payload carry), (RecordedOutputs,
         payload outputs))``)."""
-        pcfg, fcfg = self._require_base("run")
-        sig = self._signature("run", pcfg, _schedule_lens(fcfg), fcfg)
-        return executable("run", sig)(
-            _as_key(key), self.neighbors, self.degrees, self.mirror,
-            self._pi(pcfg), pcfg, fcfg,
-            steps=self.steps, n=self.n, payload=self.payload,
-            spec=self.spec, pspec=self.pspec,
-        )
+        with _CallSpan("run", 1) as span:
+            with jax.profiler.TraceAnnotation("plan.prepare"):
+                pcfg, fcfg = self._require_base("run")
+                sig = self._signature("run", pcfg, _schedule_lens(fcfg), fcfg)
+                span.signature(sig)
+                fn = executable("run", sig)
+            with jax.profiler.TraceAnnotation("plan.enqueue"):
+                return fn(
+                    _as_key(key), self.neighbors, self.degrees, self.mirror,
+                    self._pi(pcfg), pcfg, fcfg,
+                    steps=self.steps, n=self.n, payload=self.payload,
+                    spec=self.spec, pspec=self.pspec,
+                )
 
     def ensemble(self, seeds: int, base_key: jax.Array | int = 0):
         """vmap over seeds: outputs with a leading ``(seeds,)`` axis."""
-        pcfg, fcfg = self._require_base("ensemble")
-        keys = jax.random.split(_as_key(base_key), seeds)
-        sig = self._signature("ensemble", pcfg, _schedule_lens(fcfg), fcfg)
-        return executable("ensemble", sig)(
-            keys, self.neighbors, self.degrees, self.mirror,
-            self._pi(pcfg), pcfg, fcfg,
-            steps=self.steps, n=self.n, payload=self.payload,
-            spec=self.spec, pspec=self.pspec,
-        )
+        with _CallSpan("ensemble", seeds) as span:
+            with jax.profiler.TraceAnnotation("plan.prepare"):
+                pcfg, fcfg = self._require_base("ensemble")
+                keys = jax.random.split(_as_key(base_key), seeds)
+                sig = self._signature(
+                    "ensemble", pcfg, _schedule_lens(fcfg), fcfg
+                )
+                span.signature(sig)
+                fn = executable("ensemble", sig)
+            with jax.profiler.TraceAnnotation("plan.enqueue"):
+                return fn(
+                    keys, self.neighbors, self.degrees, self.mirror,
+                    self._pi(pcfg), pcfg, fcfg,
+                    steps=self.steps, n=self.n, payload=self.payload,
+                    spec=self.spec, pspec=self.pspec,
+                )
 
     # -- durable segmented execution ---------------------------------------
     #
@@ -460,64 +520,75 @@ class Plan:
         """
         from repro.sweep.scenario import as_pair, stack_configs
 
-        scenarios = self._scenarios(scenarios, "sweep_stacked")
-        base = _as_key(base_key)
-        pcfgs, fcfgs = stack_configs(scenarios)
-        pcfg0 = as_pair(scenarios[0])[0]
-        if self.payload is not None:
-            self.payload.validate(pcfg0)
-        # schedule lengths AFTER stacking: pad_bursts reconciled them
-        lens = (
-            int(jnp.shape(fcfgs.burst_times)[-1]),
-            int(jnp.shape(fcfgs.node_crash_times)[-1]),
-            int(jnp.shape(fcfgs.pacman_nodes)[-1]),
-            int(jnp.shape(fcfgs.edge_cut_times)[-1]),
-        )
-        sig = self._signature("sweep", pcfg0, lens, fcfgs)
+        with _CallSpan("sweep_stacked", seeds) as span:
+            with jax.profiler.TraceAnnotation("plan.prepare"):
+                scenarios = self._scenarios(scenarios, "sweep_stacked")
+                base = _as_key(base_key)
+                pcfgs, fcfgs = stack_configs(scenarios)
+                pcfg0 = as_pair(scenarios[0])[0]
+                if self.payload is not None:
+                    self.payload.validate(pcfg0)
+                # schedule lengths AFTER stacking: pad_bursts reconciled them
+                lens = (
+                    int(jnp.shape(fcfgs.burst_times)[-1]),
+                    int(jnp.shape(fcfgs.node_crash_times)[-1]),
+                    int(jnp.shape(fcfgs.pacman_nodes)[-1]),
+                    int(jnp.shape(fcfgs.edge_cut_times)[-1]),
+                )
+                sig = self._signature("sweep", pcfg0, lens, fcfgs)
+                span.signature(sig)
 
-        from repro.api.store import ResultStore
+                from repro.api.store import ResultStore
 
-        store = ResultStore.resolve(store)
-        skey = None
-        if store is not None:
-            # key on the pre-placement stacked leaves: device placement
-            # never changes the answer, so it must not change the key
-            skey = store.sweep_key(sig, self.graph, (pcfgs, fcfgs), seeds, base)
-            cached = store.get(skey)
-            if cached is not None:
-                return cached
+                store = ResultStore.resolve(store)
+                skey = None
+                if store is not None:
+                    # key on the pre-placement stacked leaves: device
+                    # placement never changes the answer, so it must not
+                    # change the key
+                    skey = store.sweep_key(
+                        sig, self.graph, (pcfgs, fcfgs), seeds, base
+                    )
+                    cached = store.get(skey)
+                    if cached is not None:
+                        return cached
 
-        keys = jax.random.split(base, seeds)
-        pcfgs, fcfgs, mesh = self.placement.place(
-            pcfgs, fcfgs, len(scenarios)
-        )
-        if segment_steps is None:
-            result = executable("sweep", sig)(
-                keys, self.neighbors, self.degrees, self.mirror,
-                self._pi(pcfg0), pcfgs, fcfgs,
-                steps=self.steps, n=self.n, payload=self.payload,
-                spec=self.spec, pspec=self.pspec, mesh=mesh,
-            )
-        else:
-            seg_sig = self._signature("seg_sweep", pcfg0, lens, fcfgs)
-            _carry, result = self._drive_segments(
-                "seg_sweep", seg_sig,
-                lambda: sim._init_sweep_carry(
-                    keys, self.neighbors, pcfgs, fcfgs, self.steps, self.n,
-                    self.payload,
-                ),
-                (self._pi(pcfg0), pcfgs, fcfgs), segment_steps, 2,
-                store, skey, mesh=mesh,
-            )
-        if store is not None:
-            store.put(
-                skey,
-                jax.block_until_ready(result),
-                extra_meta={"scenarios": len(scenarios), "seeds": int(seeds)},
-            )
-            if segment_steps is not None:
-                store.clear_segments(skey)
-        return result
+                keys = jax.random.split(base, seeds)
+                pcfgs, fcfgs, mesh = self.placement.place(
+                    pcfgs, fcfgs, len(scenarios)
+                )
+                if segment_steps is None:
+                    fn = executable("sweep", sig)
+            with jax.profiler.TraceAnnotation("plan.enqueue"):
+                if segment_steps is None:
+                    result = fn(
+                        keys, self.neighbors, self.degrees, self.mirror,
+                        self._pi(pcfg0), pcfgs, fcfgs,
+                        steps=self.steps, n=self.n, payload=self.payload,
+                        spec=self.spec, pspec=self.pspec, mesh=mesh,
+                    )
+                else:
+                    seg_sig = self._signature("seg_sweep", pcfg0, lens, fcfgs)
+                    _carry, result = self._drive_segments(
+                        "seg_sweep", seg_sig,
+                        lambda: sim._init_sweep_carry(
+                            keys, self.neighbors, pcfgs, fcfgs, self.steps,
+                            self.n, self.payload,
+                        ),
+                        (self._pi(pcfg0), pcfgs, fcfgs), segment_steps, 2,
+                        store, skey, mesh=mesh,
+                    )
+            if store is not None:
+                store.put(
+                    skey,
+                    jax.block_until_ready(result),
+                    extra_meta={
+                        "scenarios": len(scenarios), "seeds": int(seeds)
+                    },
+                )
+                if segment_steps is not None:
+                    store.clear_segments(skey)
+            return result
 
     def sweep(
         self,
@@ -538,25 +609,35 @@ class Plan:
         compilation unit. ``store=`` persists each group's stacked call
         (see :meth:`sweep_stacked`).
         """
-        scenarios = self._scenarios(scenarios, "sweep")
-        names = tuple(
-            getattr(s, "name", f"scenario{i}") for i, s in enumerate(scenarios)
-        )
-        results = [None] * len(scenarios)
-        payloads = [None] * len(scenarios) if self.payload is not None else None
-        for _sig, idxs in self.groups(scenarios):
-            stacked = self.sweep_stacked(
-                [scenarios[i] for i in idxs], seeds=seeds, base_key=base_key,
-                store=store, segment_steps=segment_steps,
-            )
-            if self.payload is not None:
-                stacked, stacked_payload = stacked
-            for j, i in enumerate(idxs):
-                results[i] = jax.tree_util.tree_map(lambda x: x[j], stacked)
-                if self.payload is not None:
-                    payloads[i] = jax.tree_util.tree_map(
-                        lambda x: x[j], stacked_payload
+        with _CallSpan("sweep", seeds):
+            with jax.profiler.TraceAnnotation("plan.prepare"):
+                scenarios = self._scenarios(scenarios, "sweep")
+                names = tuple(
+                    getattr(s, "name", f"scenario{i}")
+                    for i, s in enumerate(scenarios)
+                )
+                groups = self.groups(scenarios)
+            with jax.profiler.TraceAnnotation("plan.enqueue"):
+                results = [None] * len(scenarios)
+                payloads = (
+                    [None] * len(scenarios) if self.payload is not None else None
+                )
+                for _sig, idxs in groups:
+                    stacked = self.sweep_stacked(
+                        [scenarios[i] for i in idxs], seeds=seeds,
+                        base_key=base_key, store=store,
+                        segment_steps=segment_steps,
                     )
+                    if self.payload is not None:
+                        stacked, stacked_payload = stacked
+                    for j, i in enumerate(idxs):
+                        results[i] = jax.tree_util.tree_map(
+                            lambda x: x[j], stacked
+                        )
+                        if self.payload is not None:
+                            payloads[i] = jax.tree_util.tree_map(
+                                lambda x: x[j], stacked_payload
+                            )
         return SweepResult(names=names, outputs=results, payloads=payloads)
 
     # -- introspection -----------------------------------------------------

@@ -15,6 +15,15 @@ One synchronous round (time t -> t+1):
      MISSINGPERSON timeout replacement;
   6. forks/terminations execute through the slot machinery.
 
+Each stage is traced under a ``jax.named_scope`` on every round path
+(the unfused oracle, the fused CPU reference and the TPU whole-round
+branch): ``round.topology`` (1), ``round.move`` (2), ``round.threats``
+(3), ``round.observe`` (4), ``round.decide`` (5), ``round.fork`` (6) and,
+on TPU, ``round.kernel`` for the whole-round Pallas call and the
+operands built only for it; the payload hooks run under ``payload.*``.
+The scopes are HLO metadata only: they name device ops in a profiler
+trace and change no output bit.
+
 The whole trajectory runs under one ``lax.scan``; the live topology is
 part of the scan carry, so downed nodes/links persist and recover across
 steps. Configs are pytrees with *traced numeric leaves* (see
@@ -387,126 +396,139 @@ def protocol_step(
     n_before = jnp.sum(ws.active)
 
     # 1. topology evolves; a crashing node kills its resident walks
-    gs = flr.step_topology(state.graph, t, fcfg, k_topo, neighbors, mirror)
-    ws = ws._replace(
-        active=flr.kill_resident_walks(ws.active, ws.pos, gs.node_up)
-    )
-
-    # 1b. a mobile Pac-Man hops over the same live topology the walks see
-    # (dedicated stream tag 6 + 1: never perturbs the walk/decision draws)
-    pac_pos = state.pacman_pos
-    if fcfg.pacman_mobile:
-        k_pac = fold_in_time(key, t, 7)
-        pac_pos = flr.step_mobile_pacman(
-            pac_pos, t, fcfg, k_pac, neighbors, degrees,
-            availability(gs, neighbors, degrees),
+    with jax.named_scope("round.topology"):
+        gs = flr.step_topology(state.graph, t, fcfg, k_topo, neighbors, mirror)
+        ws = ws._replace(
+            active=flr.kill_resident_walks(ws.active, ws.pos, gs.node_up)
         )
+
+        # 1b. a mobile Pac-Man hops over the same live topology the walks
+        # see (dedicated stream tag 6 + 1: never perturbs the walk/decision
+        # draws)
+        pac_pos = state.pacman_pos
+        if fcfg.pacman_mobile:
+            k_pac = fold_in_time(key, t, 7)
+            pac_pos = flr.step_mobile_pacman(
+                pac_pos, t, fcfg, k_pac, neighbors, degrees,
+                availability(gs, neighbors, degrees),
+            )
 
     # 2. movement over the currently-available edges; non-uniform zoo
     # variants (jump / biased / bloom) are whole other static programs
-    if pcfg.walk_variant == "uniform":
-        ws = wlk.move_walks(
-            ws, neighbors, degrees, k_move, availability(gs, neighbors, degrees)
-        )
-    else:
-        from repro.zoo.variants import move_variant
+    with jax.named_scope("round.move"):
+        if pcfg.walk_variant == "uniform":
+            ws = wlk.move_walks(
+                ws, neighbors, degrees, k_move,
+                availability(gs, neighbors, degrees),
+            )
+        else:
+            from repro.zoo.variants import move_variant
 
-        ws = move_variant(
-            ws, pcfg, neighbors, degrees, k_move,
-            availability(gs, neighbors, degrees), gs.node_up,
-        )
+            ws = move_variant(
+                ws, pcfg, neighbors, degrees, k_move,
+                availability(gs, neighbors, degrees), gs.node_up,
+            )
 
     # 3. walk-level threat models
-    active = flr.apply_probabilistic_failures(ws.active, t, fcfg, k_pfail)
-    active = flr.apply_burst_failures(active, t, fcfg, k_burst)
-    active, byz_state = flr.step_byzantine(
-        active, ws.pos, t, state.byz_state, fcfg, k_byz
-    )
-    active = flr.apply_pacman(active, ws.pos, t, fcfg, pac_pos)
-    ws = ws._replace(active=active)
-    n_failed = n_before - jnp.sum(active)
+    with jax.named_scope("round.threats"):
+        active = flr.apply_probabilistic_failures(ws.active, t, fcfg, k_pfail)
+        active = flr.apply_burst_failures(active, t, fcfg, k_burst)
+        active, byz_state = flr.step_byzantine(
+            active, ws.pos, t, state.byz_state, fcfg, k_byz
+        )
+        active = flr.apply_pacman(active, ws.pos, t, fcfg, pac_pos)
+        ws = ws._replace(active=active)
+        n_failed = n_before - jnp.sum(active)
 
     # 4. observations: return samples + last-seen updates for ALL visitors
     impl = resolved_estimator_impl(pcfg)
-    last_seen = state.last_seen
-    prev = last_seen[ws.pos, ws.track]  # (W,)
-    r = t - prev
-    valid = ws.active & (prev != est.NEVER) & (r >= 1)
-    upd = jnp.where(ws.active, t, est.NEVER)
-    node_sums = None
     # `pi is None` guards direct callers that pass an analytic-survival
     # table independently of pcfg; the padding decision (_will_fuse,
     # observation_rows) must stay a superset-consistent view of this.
     fuse = _will_fuse(pcfg) and pi is None
-    if fuse:
-        # one fused pass: scatter + max-update + node theta-sums
-        # (kernels/round_update.py; Pallas tiles on TPU, jnp elsewhere)
-        from repro.kernels.round_update import round_update
+    with jax.named_scope("round.observe"):
+        last_seen = state.last_seen
+        prev = last_seen[ws.pos, ws.track]  # (W,)
+        r = t - prev
+        valid = ws.active & (prev != est.NEVER) & (r >= 1)
+        upd = jnp.where(ws.active, t, est.NEVER)
+        node_sums = None
+        if fuse:
+            # one fused pass: scatter + max-update + node theta-sums
+            # (kernels/round_update.py; Pallas tiles on TPU, jnp elsewhere)
+            from repro.kernels.round_update import round_update
 
-        last_seen, hist, tot, node_sums = round_update(
-            last_seen, state.rts.hist, state.rts.total,
-            ws.pos, ws.track, r, valid, upd, t,
-        )
-        rts = est.ReturnTimeState(hist=hist, total=tot)
-    else:
-        rts = est.record_returns(state.rts, ws.pos, r, valid)
-        last_seen = last_seen.at[ws.pos, ws.track].max(upd, mode="drop")
+            last_seen, hist, tot, node_sums = round_update(
+                last_seen, state.rts.hist, state.rts.total,
+                ws.pos, ws.track, r, valid, upd, t,
+            )
+            rts = est.ReturnTimeState(hist=hist, total=tot)
+        else:
+            rts = est.record_returns(state.rts, ws.pos, r, valid)
+            last_seen = last_seen.at[ws.pos, ws.track].max(upd, mode="drop")
 
-    # 5. estimation + decisions for chosen walks
-    chosen = prt.choose_walks(ws.pos, ws.active, degrees.shape[0])
+    # 5. estimation + decisions for chosen walks; 6. forks/terminations
     enabled = t >= pcfg.protocol_start
     theta_hist = state.theta_hist
     if pcfg.algorithm in ("decafork", "decafork+"):
-        if fuse:
-            theta = est.theta_hat_from_node_sums(node_sums, ws.pos)
-        elif impl == "gather" or pi is not None:
-            theta = est.theta_hat_rows(
-                last_seen, rts.hist, rts.total, t, ws.pos, ws.track, pi=pi,
-                max_elapsed=max_elapsed,
-            )
-        elif impl == "compare":
-            sums = est.node_sums_compare(last_seen, rts.hist, rts.total, t)
-            theta = est.theta_hat_from_node_sums(sums, ws.pos)
-        elif impl == "pallas":
-            from repro.kernels import theta_sums_pallas
+        with jax.named_scope("round.decide"):
+            chosen = prt.choose_walks(ws.pos, ws.active, degrees.shape[0])
+            if fuse:
+                theta = est.theta_hat_from_node_sums(node_sums, ws.pos)
+            elif impl == "gather" or pi is not None:
+                theta = est.theta_hat_rows(
+                    last_seen, rts.hist, rts.total, t, ws.pos, ws.track,
+                    pi=pi, max_elapsed=max_elapsed,
+                )
+            elif impl == "compare":
+                sums = est.node_sums_compare(last_seen, rts.hist, rts.total, t)
+                theta = est.theta_hat_from_node_sums(sums, ws.pos)
+            elif impl == "pallas":
+                from repro.kernels import theta_sums_pallas
 
-            sums = theta_sums_pallas(last_seen, rts.hist, rts.total, t)
-            theta = est.theta_hat_from_node_sums(sums, ws.pos)
-        else:
-            raise ValueError(impl)
-        # beyond-paper: per-node self-calibrated thresholds (auto_eps)
-        if pcfg.auto_eps:
-            warmup = ~enabled
-            b = jnp.clip(
-                (theta / pcfg.theta_bin_width).astype(jnp.int32),
-                0,
-                theta_hist.shape[1] - 1,
+                sums = theta_sums_pallas(last_seen, rts.hist, rts.total, t)
+                theta = est.theta_hat_from_node_sums(sums, ws.pos)
+            else:
+                raise ValueError(impl)
+            # beyond-paper: per-node self-calibrated thresholds (auto_eps)
+            if pcfg.auto_eps:
+                warmup = ~enabled
+                b = jnp.clip(
+                    (theta / pcfg.theta_bin_width).astype(jnp.int32),
+                    0,
+                    theta_hist.shape[1] - 1,
+                )
+                w = (chosen & warmup).astype(jnp.float32)
+                theta_hist = theta_hist.at[ws.pos, b].add(w, mode="drop")
+                eps_w, eps2_w = prt.theta_quantile_thresholds(
+                    theta_hist, ws.pos, pcfg
+                )
+                fork_mask, term_mask = prt.decafork_decisions(
+                    theta, chosen, k_dec, pcfg, enabled, eps=eps_w, eps2=eps2_w
+                )
+            else:
+                fork_mask, term_mask = prt.decafork_decisions(
+                    theta, chosen, k_dec, pcfg, enabled
+                )
+        with jax.named_scope("round.fork"):
+            ws = wlk.execute_terminations(ws, term_mask)
+            n_terms = jnp.sum(term_mask)
+            ws, last_seen, n_forks, fork_parent = wlk.execute_forks(
+                ws, last_seen, fork_mask, ws.pos, None, t
             )
-            w = (chosen & warmup).astype(jnp.float32)
-            theta_hist = theta_hist.at[ws.pos, b].add(w, mode="drop")
-            eps_w, eps2_w = prt.theta_quantile_thresholds(theta_hist, ws.pos, pcfg)
-            fork_mask, term_mask = prt.decafork_decisions(
-                theta, chosen, k_dec, pcfg, enabled, eps=eps_w, eps2=eps2_w
-            )
-        else:
-            fork_mask, term_mask = prt.decafork_decisions(
-                theta, chosen, k_dec, pcfg, enabled
-            )
-        ws = wlk.execute_terminations(ws, term_mask)
-        n_terms = jnp.sum(term_mask)
-        ws, last_seen, n_forks, fork_parent = wlk.execute_forks(
-            ws, last_seen, fork_mask, ws.pos, None, t
-        )
         theta_mean = jnp.sum(jnp.where(chosen, theta, 0.0)) / jnp.maximum(
             jnp.sum(chosen), 1
         )
     elif pcfg.algorithm == "missingperson":
-        ev = prt.missingperson_decisions(
-            last_seen, ws.pos, ws.track, chosen, t, k_dec, pcfg, enabled
-        )  # (W, C) — only initial-id columns (< z0) can fire
-        ws, last_seen, n_forks, fork_parent = wlk.execute_grid_forks(
-            ws, last_seen, ev, t
-        )
+        with jax.named_scope("round.decide"):
+            chosen = prt.choose_walks(ws.pos, ws.active, degrees.shape[0])
+            ev = prt.missingperson_decisions(
+                last_seen, ws.pos, ws.track, chosen, t, k_dec, pcfg, enabled
+            )  # (W, C) — only initial-id columns (< z0) can fire
+        with jax.named_scope("round.fork"):
+            ws, last_seen, n_forks, fork_parent = wlk.execute_grid_forks(
+                ws, last_seen, ev, t
+            )
         n_terms = jnp.int32(0)
         term_mask = jnp.zeros((ev.shape[0],), bool)
         theta_mean = jnp.float32(0.0)
@@ -595,111 +617,80 @@ def _protocol_step_fused(
     if _fused_round_backend() == "ref":
         # 1. topology evolves; a crashing node kills its resident walks
         # (step_topology already applies any scheduled edge cuts)
-        gs = flr.step_topology(state.graph, t, fcfg, k_topo, neighbors, mirror)
-        ws = ws._replace(
-            active=flr.kill_resident_walks(ws.active, ws.pos, gs.node_up)
-        )
-
-        # 1b. mobile Pac-Man hop — same helper, same dedicated stream as
-        # the unfused sequence, so the positions stay its exact bits
-        if fcfg.pacman_mobile:
-            k_pac = fold_in_time(key, t, 7)
-            pac_pos = flr.step_mobile_pacman(
-                pac_pos, t, fcfg, k_pac, neighbors, degrees,
-                availability(gs, neighbors, degrees),
+        with jax.named_scope("round.topology"):
+            gs = flr.step_topology(
+                state.graph, t, fcfg, k_topo, neighbors, mirror
             )
+            ws = ws._replace(
+                active=flr.kill_resident_walks(ws.active, ws.pos, gs.node_up)
+            )
+
+            # 1b. mobile Pac-Man hop — same helper, same dedicated stream
+            # as the unfused sequence, so the positions stay its exact bits
+            if fcfg.pacman_mobile:
+                k_pac = fold_in_time(key, t, 7)
+                pac_pos = flr.step_mobile_pacman(
+                    pac_pos, t, fcfg, k_pac, neighbors, degrees,
+                    availability(gs, neighbors, degrees),
+                )
 
         # 2. movement, row-restricted to the walks' own adjacency rows
-        u_move = jax.random.uniform(k_move, (W,))
-        avail_rows = availability_rows(
-            gs.edge_up[ws.pos], gs.node_up[ws.pos], gs.node_up,
-            neighbors[ws.pos], degrees[ws.pos],
-        )
-        ws = ws._replace(
-            pos=wlk.move_walks_rows(
-                ws, neighbors[ws.pos], u_move, avail_rows, degrees.dtype
+        with jax.named_scope("round.move"):
+            u_move = jax.random.uniform(k_move, (W,))
+            avail_rows = availability_rows(
+                gs.edge_up[ws.pos], gs.node_up[ws.pos], gs.node_up,
+                neighbors[ws.pos], degrees[ws.pos],
             )
-        )
+            ws = ws._replace(
+                pos=wlk.move_walks_rows(
+                    ws, neighbors[ws.pos], u_move, avail_rows, degrees.dtype
+                )
+            )
 
         # 3. walk-level threat models (same helpers, same keys)
-        active = flr.apply_probabilistic_failures(ws.active, t, fcfg, k_pfail)
-        active = flr.apply_burst_failures(active, t, fcfg, k_burst)
-        active, byz_state = flr.step_byzantine(
-            active, ws.pos, t, state.byz_state, fcfg, k_byz
-        )
-        active = flr.apply_pacman(active, ws.pos, t, fcfg, pac_pos)
-        ws = ws._replace(active=active)
-        n_failed = n_before - jnp.sum(active)
+        with jax.named_scope("round.threats"):
+            active = flr.apply_probabilistic_failures(
+                ws.active, t, fcfg, k_pfail
+            )
+            active = flr.apply_burst_failures(active, t, fcfg, k_burst)
+            active, byz_state = flr.step_byzantine(
+                active, ws.pos, t, state.byz_state, fcfg, k_byz
+            )
+            active = flr.apply_pacman(active, ws.pos, t, fcfg, pac_pos)
+            ws = ws._replace(active=active)
+            n_failed = n_before - jnp.sum(active)
 
         # 4. observations on the incremental cumulative carry
-        last_seen = state.last_seen
-        prev = last_seen[ws.pos, ws.track]
-        r = t - prev
-        valid = ws.active & (prev != est.NEVER) & (r >= 1)
-        upd = jnp.where(ws.active, t, est.NEVER)
-        rts = est.record_returns_cumulative(
-            state.rts, ws.pos, r, valid, pcfg.rt_bins
-        )
-        last_seen = last_seen.at[ws.pos, ws.track].max(upd, mode="drop")
+        with jax.named_scope("round.observe"):
+            last_seen = state.last_seen
+            prev = last_seen[ws.pos, ws.track]
+            r = t - prev
+            valid = ws.active & (prev != est.NEVER) & (r >= 1)
+            upd = jnp.where(ws.active, t, est.NEVER)
+            rts = est.record_returns_cumulative(
+                state.rts, ws.pos, r, valid, pcfg.rt_bins
+            )
+            last_seen = last_seen.at[ws.pos, ws.track].max(upd, mode="drop")
 
         # 5. estimation + decisions; no cumsum anywhere
-        chosen = prt.choose_walks_pairwise(ws.pos, ws.active)
-        theta = est.theta_hat_cumulative(
-            last_seen, rts, t, ws.pos, ws.track
-        )
-        fork_mask, term_mask = prt.decafork_decisions(
-            theta, chosen, k_dec, pcfg, enabled
-        )
+        with jax.named_scope("round.decide"):
+            chosen = prt.choose_walks_pairwise(ws.pos, ws.active)
+            theta = est.theta_hat_cumulative(
+                last_seen, rts, t, ws.pos, ws.track
+            )
+            fork_mask, term_mask = prt.decafork_decisions(
+                theta, chosen, k_dec, pcfg, enabled
+            )
     else:
         # TPU: one whole-round Pallas pass; pre-draw every uniform from
-        # the exact streams the unfused sequence consumes
+        # the exact streams the unfused sequence consumes, each under the
+        # stage that consumes it
         from repro.kernels.round_update import whole_round_pallas
 
         n_obs = state.last_seen.shape[0]
         K = fcfg.n_bursts
-        u_move = jax.random.uniform(k_move, (W,))
-        u_pfail = jax.random.uniform(k_pfail, (W,))
-        if K:
-            u_burst = jnp.stack(
-                [
-                    jax.random.uniform(jax.random.fold_in(k_burst, i), (W,))
-                    for i in range(K)
-                ]
-            )
-            burst_sizes_eff = jnp.stack(
-                [
-                    jnp.where(t == fcfg.burst_times[i], fcfg.burst_sizes[i], 0)
-                    for i in range(K)
-                ]
-            ).astype(jnp.int32)
-        else:
-            u_burst = jnp.ones((1, W), jnp.float32)
-            burst_sizes_eff = jnp.zeros((1,), jnp.int32)
-        k_fork, k_term = jax.random.split(k_dec)
-        u_fork = jax.random.uniform(k_fork, (W,))
-        u_term = jax.random.uniform(k_term, (W,))
-        u_nfail, u_nrec, e_fail, e_rec = flr.topology_uniforms(
-            k_topo, neighbors, mirror
-        )
-        sched_down = flr.scheduled_crash_mask(n, t, fcfg)
 
-        # Byzantine chain advances outside (one scalar draw); the kernel
-        # only needs "which node kills this round" (-1: none)
-        byz_armed = (t >= fcfg.byz_start_time) & (fcfg.byzantine_node >= 0)
-        flip = (jax.random.uniform(k_byz, ()) < fcfg.p_byz) & byz_armed
-        byz_state = jnp.logical_xor(state.byz_state, flip)
-        byz_kill_node = jnp.where(
-            byz_state & byz_armed, fcfg.byzantine_node, -1
-        ).astype(jnp.int32)
-        pac_armed = (t >= fcfg.pacman_start_time) & (fcfg.pacman_node >= 0)
-        pac_node = jnp.where(pac_armed, fcfg.pacman_node, -1).astype(jnp.int32)
-
-        # start-gated rates fold the gate into the threshold (u in [0,1)
-        # is never < -1, so "not started" == rate -1)
-        p_fail_eff = jnp.where(t >= fcfg.p_fail_start, fcfg.p_fail, -1.0)
-        p_nf_eff = jnp.where(t >= fcfg.node_fail_start, fcfg.p_node_fail, -1.0)
-        p_lf_eff = jnp.where(t >= fcfg.link_fail_start, fcfg.p_link_fail, -1.0)
-
+        # pad rows stay down forever: node_up False, recovery uniform 1.0
         def _pad_nodes(x, fill):
             pad = n_obs - x.shape[0]
             if pad == 0:
@@ -708,41 +699,101 @@ def _protocol_step_fused(
                 [x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)]
             )
 
-        # pad rows stay down forever: node_up False, recovery uniform 1.0
-        outs = whole_round_pallas(
-            state.last_seen, state.rts.hist, state.rts.total,
-            _pad_nodes(state.graph.node_up, False),
-            _pad_nodes(state.graph.edge_up, False),
-            ws.pos, ws.track, ws.active,
-            neighbors[ws.pos], degrees[ws.pos],
-            state.graph.edge_up[ws.pos], e_fail[ws.pos], e_rec[ws.pos],
-            u_move, u_pfail, u_fork, u_term,
-            u_burst, burst_sizes_eff,
-            _pad_nodes(u_nfail, 1.0), _pad_nodes(u_nrec, 1.0),
-            _pad_nodes(sched_down, False),
-            _pad_nodes(e_fail, 1.0), _pad_nodes(e_rec, 1.0),
-            params_f=jnp.stack(
-                [
-                    jnp.asarray(p_fail_eff, jnp.float32),
-                    jnp.asarray(p_nf_eff, jnp.float32),
-                    jnp.asarray(p_lf_eff, jnp.float32),
-                    jnp.asarray(fcfg.p_node_recover, jnp.float32),
-                    jnp.asarray(fcfg.p_link_recover, jnp.float32),
-                    jnp.asarray(pcfg.eps, jnp.float32),
-                    jnp.asarray(pcfg.eps2, jnp.float32),
-                    jnp.asarray(pcfg.p, jnp.float32),
-                ]
-            )[None, :],
-            params_i=jnp.stack(
-                [
-                    jnp.asarray(t, jnp.int32),
-                    byz_kill_node,
-                    pac_node,
-                    enabled.astype(jnp.int32),
-                ]
-            )[None, :],
-            decafork_plus=pcfg.algorithm == "decafork+",
-        )
+        with jax.named_scope("round.move"):
+            u_move = jax.random.uniform(k_move, (W,))
+        with jax.named_scope("round.threats"):
+            u_pfail = jax.random.uniform(k_pfail, (W,))
+            if K:
+                u_burst = jnp.stack(
+                    [
+                        jax.random.uniform(jax.random.fold_in(k_burst, i), (W,))
+                        for i in range(K)
+                    ]
+                )
+                burst_sizes_eff = jnp.stack(
+                    [
+                        jnp.where(
+                            t == fcfg.burst_times[i], fcfg.burst_sizes[i], 0
+                        )
+                        for i in range(K)
+                    ]
+                ).astype(jnp.int32)
+            else:
+                u_burst = jnp.ones((1, W), jnp.float32)
+                burst_sizes_eff = jnp.zeros((1,), jnp.int32)
+        with jax.named_scope("round.decide"):
+            k_fork, k_term = jax.random.split(k_dec)
+            u_fork = jax.random.uniform(k_fork, (W,))
+            u_term = jax.random.uniform(k_term, (W,))
+        with jax.named_scope("round.topology"):
+            u_nfail, u_nrec, e_fail, e_rec = flr.topology_uniforms(
+                k_topo, neighbors, mirror
+            )
+            sched_down = flr.scheduled_crash_mask(n, t, fcfg)
+            topo_pads = (
+                _pad_nodes(u_nfail, 1.0), _pad_nodes(u_nrec, 1.0),
+                _pad_nodes(sched_down, False),
+                _pad_nodes(e_fail, 1.0), _pad_nodes(e_rec, 1.0),
+            )
+
+        # Byzantine chain advances outside (one scalar draw); the kernel
+        # only needs "which node kills this round" (-1: none)
+        with jax.named_scope("round.threats"):
+            byz_armed = (t >= fcfg.byz_start_time) & (fcfg.byzantine_node >= 0)
+            flip = (jax.random.uniform(k_byz, ()) < fcfg.p_byz) & byz_armed
+            byz_state = jnp.logical_xor(state.byz_state, flip)
+            byz_kill_node = jnp.where(
+                byz_state & byz_armed, fcfg.byzantine_node, -1
+            ).astype(jnp.int32)
+            pac_armed = (
+                (t >= fcfg.pacman_start_time) & (fcfg.pacman_node >= 0)
+            )
+            pac_node = jnp.where(pac_armed, fcfg.pacman_node, -1).astype(
+                jnp.int32
+            )
+
+        with jax.named_scope("round.kernel"):
+            # start-gated rates fold the gate into the threshold (u in
+            # [0,1) is never < -1, so "not started" == rate -1)
+            p_fail_eff = jnp.where(t >= fcfg.p_fail_start, fcfg.p_fail, -1.0)
+            p_nf_eff = jnp.where(
+                t >= fcfg.node_fail_start, fcfg.p_node_fail, -1.0
+            )
+            p_lf_eff = jnp.where(
+                t >= fcfg.link_fail_start, fcfg.p_link_fail, -1.0
+            )
+            outs = whole_round_pallas(
+                state.last_seen, state.rts.hist, state.rts.total,
+                _pad_nodes(state.graph.node_up, False),
+                _pad_nodes(state.graph.edge_up, False),
+                ws.pos, ws.track, ws.active,
+                neighbors[ws.pos], degrees[ws.pos],
+                state.graph.edge_up[ws.pos], e_fail[ws.pos], e_rec[ws.pos],
+                u_move, u_pfail, u_fork, u_term,
+                u_burst, burst_sizes_eff,
+                *topo_pads,
+                params_f=jnp.stack(
+                    [
+                        jnp.asarray(p_fail_eff, jnp.float32),
+                        jnp.asarray(p_nf_eff, jnp.float32),
+                        jnp.asarray(p_lf_eff, jnp.float32),
+                        jnp.asarray(fcfg.p_node_recover, jnp.float32),
+                        jnp.asarray(fcfg.p_link_recover, jnp.float32),
+                        jnp.asarray(pcfg.eps, jnp.float32),
+                        jnp.asarray(pcfg.eps2, jnp.float32),
+                        jnp.asarray(pcfg.p, jnp.float32),
+                    ]
+                )[None, :],
+                params_i=jnp.stack(
+                    [
+                        jnp.asarray(t, jnp.int32),
+                        byz_kill_node,
+                        pac_node,
+                        enabled.astype(jnp.int32),
+                    ]
+                )[None, :],
+                decafork_plus=pcfg.algorithm == "decafork+",
+            )
         (last_seen, hist, tot, node_up_new, edge_up_new,
          pos_new, act_new, theta, chosen, fork_mask, term_mask) = outs
         gs = GraphState(node_up=node_up_new[:n], edge_up=edge_up_new[:n])
@@ -751,11 +802,12 @@ def _protocol_step_fused(
         n_failed = n_before - jnp.sum(act_new)
 
     # forks/terminations execute through the shared slot machinery
-    ws = wlk.execute_terminations(ws, term_mask)
-    n_terms = jnp.sum(term_mask)
-    ws, last_seen, n_forks, fork_parent = wlk.execute_forks(
-        ws, last_seen, fork_mask, ws.pos, None, t
-    )
+    with jax.named_scope("round.fork"):
+        ws = wlk.execute_terminations(ws, term_mask)
+        n_terms = jnp.sum(term_mask)
+        ws, last_seen, n_forks, fork_parent = wlk.execute_forks(
+            ws, last_seen, fork_mask, ws.pos, None, t
+        )
     theta_mean = jnp.sum(jnp.where(chosen, theta, 0.0)) / jnp.maximum(
         jnp.sum(chosen), 1
     )
@@ -866,8 +918,9 @@ def _scan_chunk(
         s2, out = protocol_step(
             s, pcfg, fcfg, neighbors, degrees, mirror, pi, max_elapsed=steps
         )
-        pc = payload.on_terminate(pc, out.terminated)
-        pc = payload.on_fork(pc, out.fork_parent)
+        with jax.named_scope("payload.fork"):
+            pc = payload.on_terminate(pc, out.terminated)
+            pc = payload.on_fork(pc, out.fork_parent)
         pc, pout = payload.on_visit(pc, s2.walks, t, k_visit)
         if pspec is not None:
             pout = pspec.select(pout)

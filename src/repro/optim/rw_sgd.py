@@ -216,13 +216,15 @@ class RwSgdPayload(Payload):
     def on_visit(self, rs: ReplicaSet, walks, t, key):
         from repro.data.synthetic import sample_batch
 
-        batches = jax.vmap(
-            lambda nid: sample_batch(
-                self.task, key, self.local_batch, self.seq_len, nid
-            )
-        )(walks.pos)
+        with jax.named_scope("payload.batch"):
+            batches = jax.vmap(
+                lambda nid: sample_batch(
+                    self.task, key, self.local_batch, self.seq_len, nid
+                )
+            )(walks.pos)
         do = walks.active & (t % self.train_every == 0)
-        rs, losses = self._train(rs, batches, do)
+        with jax.named_scope("payload.step"):
+            rs, losses = self._train(rs, batches, do)
         n_trained = jnp.sum(do)
         mean = jnp.sum(losses) / jnp.maximum(n_trained, 1)
         return rs, RwSgdOutputs(

@@ -142,3 +142,50 @@ def test_paper_config_takes_whole_round_kernel_on_tpu(monkeypatch, alg):
     assert sim.resolved_estimator_impl(pcfg) == "fused"
     decision = sim.round_impl_decision(pcfg)
     assert (decision.impl, decision.backend) == ("fused", "pallas"), decision
+
+
+def test_whole_round_program_carries_stage_scopes_for_v5e(one_chip, monkeypatch):
+    """``Plan.ensemble``'s TPU program, compiled for a described v5e at
+    the paper cell's widths, names its round stages: the pre-drawn
+    topology uniforms, the whole-round kernel and the fork machinery each
+    under their scope (what a profiler trace of the chip reports)."""
+    import re
+
+    from repro.api import Experiment
+    from repro.api import plan as plan_mod
+    from repro.core import FailureConfig, ProtocolConfig
+    from repro.graphs import random_regular_graph
+
+    monkeypatch.delenv("REPRO_ROUND_IMPL", raising=False)
+    monkeypatch.delenv("REPRO_ESTIMATOR_IMPL", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    p = Experiment(
+        graph=random_regular_graph(N, D, seed=0),
+        protocol=ProtocolConfig(z0=10, max_walks=W, rt_bins=B, protocol_start=10),
+        failures=FailureConfig(burst_times=(20, 30), burst_sizes=(5, 6)),
+        steps=40,
+    ).plan()
+    pcfg, fcfg = p._require_base("ensemble")
+    sig = p._signature("ensemble", pcfg, plan_mod._schedule_lens(fcfg), fcfg)
+    args = (jax.random.split(jax.random.key(0), 2), p.neighbors, p.degrees,
+            p.mirror, p._pi(pcfg), pcfg, fcfg)
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            jnp.shape(x), jnp.result_type(x), sharding=one_chip,
+            weak_type=not hasattr(x, "dtype"),
+        ),
+        args,
+    )
+    text = plan_mod.executable("ensemble", sig).lower(
+        *shapes, steps=p.steps, n=p.n, payload=None, spec=p.spec, pspec=None,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    scopes = {
+        part
+        for name in re.findall(r'op_name="([^"]*)"', text)
+        for part in name.split("/")
+        if part.startswith("round.")
+    }
+    assert {"round.topology", "round.kernel", "round.fork"} <= scopes
+    kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert all("round.kernel/" in ln for ln in kernel)
