@@ -19,8 +19,9 @@ a slot freed this round is immediately reallocatable, so a terminated
       Walk ``fork_parent[s]`` (>= 0) was duplicated into slot ``s`` this
       round; copy slot state parent -> child (DECAFORK's "identical
       copy"). ``fork_parent`` is the per-slot parent map emitted by
-      ``walkers.execute_forks`` (slot allocation itself happens there,
-      via ``walkers.allocate_fork_slots``); payloads only mirror it.
+      ``walkers.execute_forks`` or, for MISSINGPERSON's event grid,
+      ``walkers.execute_grid_forks`` (slot allocation itself happens
+      there); payloads only mirror it.
   ``on_visit(carry, walks, t, key)``
       The per-round local step: ``walks.pos[s]`` is the node slot ``s``
       sits on *after* this round's hop, ``walks.active[s]`` whether the

@@ -527,7 +527,7 @@ def protocol_step(
             )  # (W, C) — only initial-id columns (< z0) can fire
         with jax.named_scope("round.fork"):
             ws, last_seen, n_forks, fork_parent = wlk.execute_grid_forks(
-                ws, last_seen, ev, t
+                ws, last_seen, ev
             )
         n_terms = jnp.int32(0)
         term_mask = jnp.zeros((ev.shape[0],), bool)

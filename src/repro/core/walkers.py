@@ -4,8 +4,13 @@ Fixed-shape formulation of a dynamic population: the system owns
 ``max_walks`` slots; a slot is a walk iff ``active[slot]``. Forking
 allocates a free slot (events beyond capacity are dropped — a documented
 truncation of the paper's unbounded walk population); termination frees
-the slot. ``track[slot]`` names the column of the per-node ``last_seen``
-table the walk writes to: for DECAFORK each slot owns its own column
+the slot. Allocation follows one order rule: the r-th fork event (in
+event order; row-major for the MISSINGPERSON grid) takes the r-th free
+slot in slot order. ``execute_forks`` applies it event-side (each event
+finds its slot); ``execute_grid_forks`` slot-side (each free slot finds
+the event of its rank), so no op spans the grid's W*C events.
+``track[slot]`` names the column of the per-node ``last_seen`` table the
+walk writes to: for DECAFORK each slot owns its own column
 (fresh identity per fork, cleared on slot reuse); for MISSINGPERSON the
 track is the *initial id* in [Z_0] being replaced, so replacements share
 the identity of the walk they replace — exactly the paper's semantics.
@@ -150,26 +155,78 @@ def allocate_fork_slots(active: jax.Array, ev_mask: jax.Array):
     return safe_slot, ev_ok, ev_slot
 
 
+def _exact_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` in float32 for small integer counts: every product and sum
+    is an integer below 2**24, so exact. On TPU it runs on the MXU, where
+    a cumsum lowers to a slow windowed reduction."""
+    return jnp.dot(
+        a.astype(jnp.float32),
+        b.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def _prefix_sums(x: jax.Array) -> jax.Array:
+    """Inclusive int32 prefix sums of small counts along the last axis, as
+    an exact product with a 0/1 upper triangle."""
+    i = jnp.arange(x.shape[-1])
+    return _exact_dot(x, i[:, None] <= i[None, :]).astype(jnp.int32)
+
+
 def execute_grid_forks(
     ws: WalkState,
     last_seen: jax.Array,  # (n, C)
     ev: jax.Array,  # (W, C) bool event grid: (parent walk, identity)
-    t: jax.Array,
 ):
     """MISSINGPERSON-shaped fork grid: event ``(k, l)`` forks a duplicate
     of walk ``k`` carrying identity ``l`` (replacing missing walk ``l``).
 
-    The flat per-event origin/track/parent indices are *derived* from the
-    event's grid coordinates (row = parent, column = track, origin =
-    parent's node) instead of materializing three broadcast ``(W*C,)``
-    index arrays at every call site.
+    Same result as ``execute_forks`` on the flattened grid (origin
+    ``pos[k]``, track ``l``, parent ``k``), allocated slot-side: the free
+    slot of rank r takes the r-th event in row-major order. Its parent
+    row ``k`` is the number of rows whose events all rank below r; with
+    j = r minus the events of rows before ``k``, its identity ``l`` is
+    the number of leading columns of row ``k`` that hold at most j
+    events. Every op is (W,), (W, W), (W, C) or (C, C): there is no
+    scatter, sort or cumsum over the W*C events. ``last_seen`` is
+    returned untouched: a replacement reuses the missing id's history.
     """
-    W, C = ev.shape
-    e = jnp.arange(W * C, dtype=jnp.int32)
-    parent = e // C
-    track = e % C
-    return execute_forks(
-        ws, last_seen, ev.reshape(-1), ws.pos[parent], track, t, parent
+    W = ev.shape[0]
+    rows = jnp.arange(W, dtype=jnp.int32)
+    row_n = jnp.sum(ev, axis=1, dtype=jnp.int32)
+    row_end = _prefix_sums(row_n)  # events in rows <= k
+    free = ~ws.active
+    rank = _prefix_sums(free) - 1  # rank among free slots
+    fill = free & (rank < row_end[-1])
+    k = jnp.sum(row_end[None, :] <= rank[:, None], axis=1, dtype=jnp.int32)
+    parent = k[:, None] == rows[None, :]  # (W, W) one-hot, empty if k == W
+
+    def of_parent(x):  # x[k] per slot, 0 where k == W
+        sel = parent.reshape(parent.shape + (1,) * (x.ndim - 1))
+        return jnp.sum(jnp.where(sel, x[None], 0), axis=1).astype(x.dtype)
+
+    j = rank - of_parent(row_end - row_n)  # rank within the parent's row
+    # events up to each column of the parent's row (row k of ``ev`` by a
+    # one-hot product)
+    seen_k = _prefix_sums(_exact_dot(parent, ev))
+    track = jnp.sum(seen_k <= j[:, None], axis=1, dtype=jnp.int32)
+
+    prev, bloom = ws.prev, ws.bloom
+    if prev is not None:
+        prev = jnp.where(fill, of_parent(prev), prev)
+    if bloom is not None:
+        bloom = jnp.where(fill[:, None], of_parent(bloom), bloom)
+    return (
+        WalkState(
+            pos=jnp.where(fill, of_parent(ws.pos), ws.pos),
+            active=ws.active | fill,
+            track=jnp.where(fill, track, ws.track),
+            prev=prev,
+            bloom=bloom,
+        ),
+        last_seen,
+        jnp.sum(fill),
+        jnp.where(fill, k, -1),
     )
 
 

@@ -144,30 +144,23 @@ def test_paper_config_takes_whole_round_kernel_on_tpu(monkeypatch, alg):
     assert (decision.impl, decision.backend) == ("fused", "pallas"), decision
 
 
-def test_whole_round_program_carries_stage_scopes_for_v5e(one_chip, monkeypatch):
-    """``Plan.ensemble``'s TPU program, compiled for a described v5e at
-    the paper cell's widths, names its round stages: the pre-drawn
-    topology uniforms, the whole-round kernel and the fork machinery each
-    under their scope (what a profiler trace of the chip reports)."""
-    import re
-
+def _ensemble_hlo(one_chip, protocol, seeds):
+    """Optimised HLO of ``Plan.ensemble``'s program over ``seeds`` seeds,
+    compiled for the described chip at the paper cell's widths."""
     from repro.api import Experiment
     from repro.api import plan as plan_mod
-    from repro.core import FailureConfig, ProtocolConfig
+    from repro.core import FailureConfig
     from repro.graphs import random_regular_graph
 
-    monkeypatch.delenv("REPRO_ROUND_IMPL", raising=False)
-    monkeypatch.delenv("REPRO_ESTIMATOR_IMPL", raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     p = Experiment(
         graph=random_regular_graph(N, D, seed=0),
-        protocol=ProtocolConfig(z0=10, max_walks=W, rt_bins=B, protocol_start=10),
+        protocol=protocol,
         failures=FailureConfig(burst_times=(20, 30), burst_sizes=(5, 6)),
         steps=40,
     ).plan()
     pcfg, fcfg = p._require_base("ensemble")
     sig = p._signature("ensemble", pcfg, plan_mod._schedule_lens(fcfg), fcfg)
-    args = (jax.random.split(jax.random.key(0), 2), p.neighbors, p.degrees,
+    args = (jax.random.split(jax.random.key(0), seeds), p.neighbors, p.degrees,
             p.mirror, p._pi(pcfg), pcfg, fcfg)
     shapes = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(
@@ -176,9 +169,26 @@ def test_whole_round_program_carries_stage_scopes_for_v5e(one_chip, monkeypatch)
         ),
         args,
     )
-    text = plan_mod.executable("ensemble", sig).lower(
+    return plan_mod.executable("ensemble", sig).lower(
         *shapes, steps=p.steps, n=p.n, payload=None, spec=p.spec, pspec=None,
     ).compile().as_text()
+
+
+def test_whole_round_program_carries_stage_scopes_for_v5e(one_chip, monkeypatch):
+    """``Plan.ensemble``'s TPU program, compiled for a described v5e at
+    the paper cell's widths, names its round stages: the pre-drawn
+    topology uniforms, the whole-round kernel and the fork machinery each
+    under their scope (what a profiler trace of the chip reports)."""
+    import re
+
+    from repro.core import ProtocolConfig
+
+    monkeypatch.delenv("REPRO_ROUND_IMPL", raising=False)
+    monkeypatch.delenv("REPRO_ESTIMATOR_IMPL", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _ensemble_hlo(
+        one_chip, ProtocolConfig(z0=10, max_walks=W, rt_bins=B, protocol_start=10), 2
+    )
     assert "tpu_custom_call" in text
     scopes = {
         part
@@ -189,3 +199,33 @@ def test_whole_round_program_carries_stage_scopes_for_v5e(one_chip, monkeypatch)
     assert {"round.topology", "round.kernel", "round.fork"} <= scopes
     kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert all("round.kernel/" in ln for ln in kernel)
+
+
+def test_missingperson_round_scatters_no_event_grid_for_v5e(one_chip, monkeypatch):
+    """The unfused MissingPerson round, compiled for a described v5e at the
+    paper cell's shapes over 50 seeds, allocates fork slots without a
+    sort and without a scatter whose updates span the W*C event grid
+    (``execute_grid_forks`` works slot-side)."""
+    import math
+    import re
+
+    from repro.core import ProtocolConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _ensemble_hlo(
+        one_chip,
+        ProtocolConfig(algorithm="missingperson", z0=10, max_walks=W, rt_bins=B,
+                       protocol_start=10, eps_mp=400.0),
+        SEEDS,
+    )
+    assert "round.fork/" in text and "/while/body/" in text
+    assert not re.search(r" sort\(", text)
+    size = {
+        name: dims
+        for name, dims in re.findall(r"^\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*)\]", text, re.M)
+    }
+    updates = re.findall(r" scatter\(%[^,]+, %[^,]+, (%[^),]+)\)", text)
+    assert updates, "no scatter at all: the pattern no longer reads the HLO"
+    for name in updates:
+        dims = [int(d) for d in size[name].split(",") if d]
+        assert math.prod(dims) < SEEDS * W * C, (name, dims)

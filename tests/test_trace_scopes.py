@@ -93,12 +93,13 @@ def test_unfused_missingperson_round_scopes_the_event_grid():
     assert decision.impl == "unfused", decision
     ops = _body_ops(exp)
     assert set(STAGES) <= {_stage(name) for _, name in ops}
-    # execute_grid_forks derives each event's parent walk as e // C and
-    # ranks the W*C events into free slots (a cumsum)
-    assert _stages_of(ops, "jit(floor_divide)") == {"round.fork"}
-    ranks = [name for op, name in ops if op == "reduce-window"
-             and _stage(name) == "round.fork"]
-    assert ranks, "no rank of the event grid under round.fork"
+    # execute_grid_forks ranks the free slots and the grid's rows, and
+    # reads each slot's parent row, by small exact products; it scatters,
+    # sorts and cumsums nothing
+    assert _stages_of(ops, "dot_general") == {"round.fork"}
+    fork = {op for op, name in ops if _stage(name) == "round.fork"}
+    assert "dot" in fork, "no rank of the event grid under round.fork"
+    assert not fork & {"scatter", "sort", "reduce-window"}, fork
     assert _stages_of(ops, "select_available_edge") == {"round.move"}
 
 

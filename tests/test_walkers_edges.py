@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import walkers as wlk
 from repro.core.estimator import NEVER
@@ -113,6 +114,95 @@ def test_forks_execute_inside_jit_and_vmap():
     n, z = jax.jit(jax.vmap(fork_once))(jax.random.split(jax.random.key(0), 3))
     np.testing.assert_array_equal(np.asarray(n), [2, 2, 2])
     np.testing.assert_array_equal(np.asarray(z), [5, 5, 5])
+
+
+# ---------------------------------------------------------------------------
+# MISSINGPERSON event grid: slot-side allocation == event-side allocation
+# ---------------------------------------------------------------------------
+
+
+def _flat_grid_forks(ws, last_seen, ev):
+    """``execute_forks`` on the flattened (W, C) grid: event e forks walk
+    e // C with identity e % C from the parent's node."""
+    W, C = ev.shape
+    e = jnp.arange(W * C, dtype=jnp.int32)
+    return wlk.execute_forks(
+        ws, last_seen, ev.reshape(-1), ws.pos[e // C], e % C, jnp.int32(11), e // C
+    )
+
+
+def _grid_case(seed, W, C, n_active, density, zoo=False):
+    """A walk state with ``n_active`` random slots live and a (W, C) event
+    grid of the given density."""
+    rng = np.random.default_rng(seed)
+    active = np.zeros(W, bool)
+    active[rng.permutation(W)[:n_active]] = True
+    n = 12
+    ws = wlk.WalkState(
+        pos=jnp.asarray(rng.integers(0, n, W), jnp.int32),
+        active=jnp.asarray(active),
+        track=jnp.asarray(rng.integers(0, C, W), jnp.int32),
+        prev=jnp.asarray(rng.integers(0, n, W), jnp.int32) if zoo else None,
+        bloom=jnp.asarray(rng.random((W, 16)) < 0.5) if zoo else None,
+    )
+    last_seen = jnp.asarray(rng.integers(-5, 9, (n, C)), jnp.int32)
+    ev = jnp.asarray(rng.random((W, C)) < density)
+    return ws, last_seen, ev
+
+
+def _last_cell_case():
+    ws, ls, ev = _grid_case(5, 8, 8, 5, 0.0)
+    return ws, ls, ev.at[-1, -1].set(True).at[-1, 3].set(True).at[2, -1].set(True)
+
+
+GRID_CASES = {
+    "no_events": lambda: _grid_case(0, 8, 8, 3, 0.0),
+    "fewer_events_than_free": lambda: _grid_case(1, 8, 8, 2, 0.05),
+    "overflow": lambda: _grid_case(2, 8, 8, 3, 0.4),
+    "no_free_slot": lambda: _grid_case(3, 8, 8, 8, 0.5),
+    "last_row_and_column": _last_cell_case,
+    "c_ne_w": lambda: _grid_case(4, 8, 5, 4, 0.1),
+    "zoo_prev_bloom": lambda: _grid_case(6, 8, 8, 3, 0.08, zoo=True),
+}
+# what each case promises, as (events, free slots) -> bool
+GRID_SHAPES = {
+    "no_events": lambda e, f: e == 0 < f,
+    "fewer_events_than_free": lambda e, f: 0 < e < f,
+    "overflow": lambda e, f: e > f > 0,
+    "no_free_slot": lambda e, f: f == 0 < e,
+    "last_row_and_column": lambda e, f: e == f == 3,
+    "c_ne_w": lambda e, f: 0 < e,
+    "zoo_prev_bloom": lambda e, f: 0 < e <= f,
+}
+
+
+def _assert_same_forks(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("case", [*GRID_CASES, "jit_vmap_seeds"])
+def test_grid_forks_match_flattened_execute_forks(case):
+    """``execute_grid_forks`` allocates slot-side; ``execute_forks`` on the
+    flattened grid event-side. Both give the same state, ``last_seen``,
+    fork count and ``fork_parent``, bit for bit."""
+    if case == "jit_vmap_seeds":
+        cases = [_grid_case(s, 16, 16, s % 17, d)
+                 for s, d in enumerate([0.0, 0.02, 0.1, 0.3, 0.02, 1.0])]
+        args = jax.tree.map(lambda *x: jnp.stack(x), *cases)
+        want = jax.jit(jax.vmap(_flat_grid_forks))(*args)
+        got = jax.jit(jax.vmap(wlk.execute_grid_forks))(*args)
+    else:
+        args = GRID_CASES[case]()
+        assert GRID_SHAPES[case](int(jnp.sum(args[2])), int(jnp.sum(~args[0].active)))
+        want = _flat_grid_forks(*args)
+        got = wlk.execute_grid_forks(*args)
+    _assert_same_forks(got, want)
+    n_ev = jnp.sum(args[2], axis=(-2, -1))
+    np.testing.assert_array_equal(
+        np.asarray(want[2]), np.minimum(n_ev, jnp.sum(~args[0].active, axis=-1))
+    )
 
 
 # ---------------------------------------------------------------------------
