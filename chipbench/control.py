@@ -33,36 +33,36 @@ def control_readings(cell, seed: int, n_studies: int) -> dict:
     """``{control: numbers}`` for each control of the cell."""
     import itertools
 
-    from chipbench import check, graphs, reference, studies, tasks
+    from chipbench import check, graphs, reference, studies
 
-    cfg = cell.config
+    cfg, kind = cell.config, cell.kind
     neighbors = graphs.make(cfg["graph"])
-    task = tasks.make(cfg["payload"]) if cfg.get("payload") else None
-    controls = ("bfloat16", "bfloat16-payload") if task is not None else ("bfloat16",)
+    inputs = kind.inputs(cfg["payload"]) if kind else None
+    controls = ("bfloat16", "bfloat16-payload") if kind else ("bfloat16",)
     done = list(itertools.islice(studies.studies(cell.traffic, seed), n_studies))
     nums = {c: [] for c in controls}
     for st, i in studies.sample(cell.traffic, seed, done):
-        ref = reference.replay(cfg, neighbors, st, i, task)
+        ref = reference.replay(cfg, neighbors, st, i, kind, inputs)
         for c in controls:
-            low = reference.replay(cfg, neighbors, st, i, task, precision=c)
+            low = reference.replay(cfg, neighbors, st, i, kind, inputs, precision=c)
             nums[c].append(check.compare_trajectory(low, ref))
     return {f"control.{c}": check.combine(n) for c, n in nums.items()}
 
 
 def sound_readings(cell, seeds):
     """``(seed, numbers)`` of the unbroken program, one study per seed."""
-    from chipbench import graphs, run, studies, tasks
+    from chipbench import graphs, run, studies
     from chipbench.program import Program
 
     cfg, traffic = cell.config, cell.traffic
     neighbors = graphs.make(cfg["graph"])
-    task = tasks.make(cfg["payload"]) if cfg.get("payload") else None
+    inputs = cell.kind.inputs(cfg["payload"]) if cell.kind else None
     protocols = list(dict.fromkeys(s["protocol"] for s in traffic["studies"]))
-    program = Program(cfg, neighbors, protocols, task)
+    program = Program(cfg, neighbors, protocols, cell.kind, inputs)
     for seed in seeds:
         st = next(studies.studies(traffic, seed))
         done = [(st, program.fetch(program.dispatch(st)))]
-        yield seed, run.judge(cell, seed, done, neighbors, task)[0]
+        yield seed, run.judge(cell, seed, done, neighbors, inputs)[0]
 
 
 def main(argv=None) -> int:
@@ -99,7 +99,7 @@ def main(argv=None) -> int:
             show(seed, "sound", nums, t)
             t = time.perf_counter()
         return 0
-    with faults.planted(cell.config, args.fault):
+    with faults.planted(cell.kind, args.fault):
         for seed in seeds:
             t = time.perf_counter()
             if args.fault is None:

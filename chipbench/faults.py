@@ -3,11 +3,13 @@
 Each fault breaks the program underneath a whole run, the way a wrong
 optimisation would: a round or a local step that returns its state
 unchanged, half of the batch left out with the mean taken over the rest,
-an answer altered where it is produced; for the learning payload also a
-fork that leaves the child's replica uncopied, and a step count that
-counts dead slots as trained. (Every cell runs on one chip, so no
-exchange between chips can be left out.) Used by the tests on the CPU and
-by ``control.py`` on the chip.
+an answer altered where it is produced. A cell with a learning payload
+takes the faults its kind plants in the payload (``payloads/<kind>.py``,
+``faults()``; for ``rwsgd`` these three in the replicas' step, a fork
+that leaves the child's replica uncopied, and a step count that counts
+dead slots as trained). (Every cell runs on one chip, so no exchange
+between chips can be left out.) Used by the tests on the CPU and by
+``control.py`` on the chip.
 """
 from __future__ import annotations
 
@@ -46,48 +48,13 @@ def _sweep_faults():
     }
 
 
-def _learn_faults():
-    from repro.models.model import Model
-    from repro.optim import rw_sgd
-
-    make = rw_sgd.replica_train_step
-    loss = Model.loss
-    visit = rw_sgd.RwSgdPayload.on_visit
-
-    def unchanged_state(loss_fn, optimizer):
-        train = make(loss_fn, optimizer)
-        return lambda rs, batches, active: (rs, train(rs, batches, active)[1])
-
-    def half_batch(self, params, batch):
-        return loss(self, params, {k: v[: v.shape[0] // 2] for k, v in batch.items()})
-
-    def altered_answer(self, rs, walks, t, key):
-        rs, out = visit(self, rs, walks, t, key)
-        return rs, out._replace(loss=out.loss * 1.01)
-
-    def uncopied_fork(self, rs, fork_parent):
-        return rs
-
-    def miscounted_steps(self, rs, walks, t, key):
-        rs, out = visit(self, rs, walks, t, key)
-        return rs, out._replace(trained=out.trained * 0 + out.loss.shape[0])
-
-    return {
-        "unchanged_state": [(rw_sgd, "replica_train_step", unchanged_state)],
-        "half_batch": [(Model, "loss", half_batch)],
-        "altered_answer": [(rw_sgd.RwSgdPayload, "on_visit", altered_answer)],
-        "uncopied_fork": [(rw_sgd.RwSgdPayload, "on_fork", uncopied_fork)],
-        "miscounted_steps": [(rw_sgd.RwSgdPayload, "on_visit", miscounted_steps)],
-    }
-
-
 NAMES = ("unchanged_state", "half_batch", "altered_answer")
-LEARN_NAMES = NAMES + ("uncopied_fork", "miscounted_steps")
 
 
-def names(config: dict) -> tuple:
-    """The faults a cell of ``config`` can have."""
-    return LEARN_NAMES if config.get("payload") else NAMES
+def names(kind) -> tuple:
+    """The faults a cell can have: its payload kind's (``payloads.Kind``),
+    or the sweep's where it has none."""
+    return NAMES if kind is None else tuple(kind.faults())
 
 
 def _set(obj, name, value):
@@ -98,16 +65,17 @@ def _set(obj, name, value):
 
 
 @contextlib.contextmanager
-def planted(config: dict, fault: str | None):
+def planted(kind, fault: str | None):
     """Run the body with ``fault`` planted in the program (None: none),
-    tracing and compiling anew on the way in and out."""
+    tracing and compiling anew on the way in and out; ``kind`` as for
+    ``names``."""
     import jax
 
     from repro.api import plan
 
     patches = []
     if fault is not None:
-        table = _learn_faults() if config.get("payload") else _sweep_faults()
+        table = _sweep_faults() if kind is None else kind.faults()
         patches = table[fault]
     saved = [(o, n, o[n] if isinstance(o, dict) else getattr(o, n)) for o, n, _ in patches]
     plan.clear_cache()

@@ -1,9 +1,11 @@
 """The system under test, driven through its normal entry.
 
-The one module of the benchmark that imports ``repro``: it turns a
-configuration into ``Experiment(...).plan()`` objects and runs a study as
-``Plan.ensemble``. Inputs it hands over (the graph, the learning task's
-transition table) are made by the benchmark, not taken from the program.
+It turns a configuration into ``Experiment(...).plan()`` objects and runs
+a study as ``Plan.ensemble``. Beside it, only a payload kind's builder
+(``payloads/<kind>.py``, which makes the program's payload object) and the
+planted faults (``faults.py``) import ``repro``. Inputs handed over (the
+graph, the learning task) are made by the benchmark, not taken from the
+program.
 """
 from __future__ import annotations
 
@@ -14,9 +16,11 @@ from chipbench import spec
 
 
 class Program:
-    """One ``Plan`` per protocol the traffic submits, built once."""
+    """One ``Plan`` per protocol the traffic submits, built once; with a
+    learning payload, ``kind`` is the configuration's payload kind
+    (``payloads.of``) and ``inputs`` what its ``inputs`` made."""
 
-    def __init__(self, config: dict, neighbors: np.ndarray, protocols, task=None):
+    def __init__(self, config: dict, neighbors: np.ndarray, protocols, kind=None, inputs=None):
         from repro.api import Experiment
         from repro.core import FailureConfig, ProtocolConfig
         from repro.graphs.generators import Graph
@@ -31,7 +35,7 @@ class Program:
         failures = FailureConfig(
             burst_times=tuple(f["burst_times"]), burst_sizes=tuple(f["burst_sizes"])
         )
-        self.payload = make_payload(config, task) if config.get("payload") else None
+        self.payload = kind.build(config, inputs) if kind is not None else None
         self.plans = {
             name: Experiment(
                 graph=graph,
@@ -80,23 +84,3 @@ class Program:
             fields.update(loss=np.asarray(pay.loss), trained=np.asarray(pay.trained))
         return fields
 
-
-def make_payload(config: dict, task):
-    """``RwSgdPayload`` for the configuration's ``payload`` entry, over the
-    transition table the benchmark made (``task``: float32 (V, V))."""
-    from repro.data.synthetic import SyntheticTask
-    from repro.models.config import ModelConfig
-    from repro.models.model import Model
-    from repro.optim import RwSgdPayload, adamw
-
-    p = config["payload"]
-    model = ModelConfig(**p["model"])
-    opt = p["optimizer"]
-    return RwSgdPayload(
-        Model(model),
-        adamw(opt["lr"], b1=opt["b1"], b2=opt["b2"], eps=opt["eps"]),
-        SyntheticTask(logits=jax.numpy.asarray(task), entropy=float("nan")),
-        max_walks=config["protocol"]["max_walks"],
-        local_batch=p["local_batch"],
-        seq_len=p["seq_len"],
-    )

@@ -18,3 +18,4 @@ class Record:
     compile: dict  # clock.EVENTS seen during set-up: {event: (count, seconds)}
     peaks: dict | None  # the device's row of peaks.json (None off the chip)
     trace: object = None  # trace.Summary of the window (--trace 1 only)
+    kind: object = None  # payloads.Kind of the config's payload (None: walks alone)

@@ -72,7 +72,7 @@ def run(cell, seed: int, seconds: float, trace: bool, *, require_tpu=True, t0=No
     import jax
 
     import repro  # noqa: F401  the system under test; without it, no run
-    from chipbench import check, graphs, studies, tasks
+    from chipbench import check, graphs, studies
     from chipbench import trace as trace_mod
     from chipbench.clock import CompileClock
     from chipbench.program import Program
@@ -93,9 +93,9 @@ def run(cell, seed: int, seconds: float, trace: bool, *, require_tpu=True, t0=No
     clock = CompileClock()
     cfg, traffic = cell.config, cell.traffic
     neighbors = graphs.make(cfg["graph"])
-    task = tasks.make(cfg["payload"]) if cfg.get("payload") else None
+    inputs = cell.kind.inputs(cfg["payload"]) if cell.kind else None
     protocols = list(dict.fromkeys(s["protocol"] for s in traffic["studies"]))
-    program = Program(cfg, neighbors, protocols, task)
+    program = Program(cfg, neighbors, protocols, cell.kind, inputs)
     for st in studies.warmups(traffic, seed):
         program.fetch(program.dispatch(st))
     setup_compile = clock.take()
@@ -144,13 +144,13 @@ def run(cell, seed: int, seconds: float, trace: bool, *, require_tpu=True, t0=No
 
     # the reference, after the window and after the memory peak was read
     t_ref = time.perf_counter()
-    numbers, n_ref = judge(cell, seed, done, neighbors, task)
+    numbers, n_ref = judge(cell, seed, done, neighbors, inputs)
     correct, compared = check.verdict(numbers, cfg["limits"])
     ref_s = time.perf_counter() - t_ref
 
     rec = Record(
         config=cfg, setup_s=setup_s, window_s=window_s, studies=done,
-        compile=setup_compile, peaks=peaks, trace=summary,
+        compile=setup_compile, peaks=peaks, trace=summary, kind=cell.kind,
     )
     metrics = {}
     for m in cell.per_layer if trace else cell.end_to_end:
@@ -193,16 +193,17 @@ def run(cell, seed: int, seconds: float, trace: bool, *, require_tpu=True, t0=No
     return result
 
 
-def judge(cell, seed: int, done: list, neighbors, task) -> tuple:
+def judge(cell, seed: int, done: list, neighbors, inputs) -> tuple:
     """The compared numbers of the finished studies ``done`` (pairs of
     study and outputs on the host): the reference replays the trajectories
-    ``studies.sample`` draws from ``seed``. Returns ``(numbers, count)``."""
+    ``studies.sample`` draws from ``seed``, over the payload's ``inputs``.
+    Returns ``(numbers, count)``."""
     from chipbench import check, reference, studies
 
     per_traj = []
     by_index = {st.index: host for st, host in done}
     for st, i in studies.sample(cell.traffic, seed, [st for st, _ in done]):
-        ref = reference.replay(cell.config, neighbors, st, i, task)
+        ref = reference.replay(cell.config, neighbors, st, i, cell.kind, inputs)
         prog = {k: v[i] for k, v in by_index[st.index].items()}
         per_traj.append(check.compare_trajectory(prog, ref))
     return check.combine(per_traj), len(per_traj)

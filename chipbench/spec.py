@@ -1,10 +1,13 @@
 """Resolve a cell of ``BENCHMARK.json`` to the files that define it.
 
 Everything is found by name: configuration ``file`` as listed, traffic
-``traffic/<traffic>.json``, and one reader ``metrics/<metric>.py`` per
-metric; a metric split by the cells it moves (``<metric>.<split>``)
-without a reader of its own takes ``metrics/<metric>.py``. Adding a cell, a configuration, a traffic mix or a metric is
-adding files and entries; nothing here changes.
+``traffic/<traffic>.json``, one reader ``metrics/<metric>.py`` per
+metric, and a learning payload's kind by ``payloads.of`` (its builder
+``payloads/<kind>.py`` and reference ``reference/<kind>.py``); a metric
+split by the cells it moves (``<metric>.<split>``) without a reader of
+its own takes ``metrics/<metric>.py``. Adding a cell, a configuration, a
+payload kind, a traffic mix or a metric is adding files and entries;
+nothing here changes.
 """
 from __future__ import annotations
 
@@ -12,6 +15,8 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+
+from chipbench import payloads
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -32,6 +37,7 @@ class Cell:
     traffic: dict
     end_to_end: tuple  # Metric
     per_layer: tuple  # Metric
+    kind: object = None  # payloads.Kind of the config's payload; None: walks alone
 
 
 def protocol(config: dict, name: str) -> dict:
@@ -87,4 +93,5 @@ def resolve(name: str, bench: dict | None = None, root: Path = ROOT) -> Cell:
         traffic=traffic,
         end_to_end=metrics("end_to_end"),
         per_layer=metrics("per_layer"),
+        kind=payloads.of(config),
     )

@@ -254,16 +254,16 @@ def measure(cell, seed: int, seconds: float, *, require_tpu=True) -> tuple:
 
     import jax
 
-    from chipbench import graphs, studies, tasks
+    from chipbench import graphs, studies
     from chipbench.program import Program
 
     dev = jax.devices()[0]
     if require_tpu and dev.platform != "tpu":
         raise SystemExit(f"chipbench: no TPU; JAX sees {dev.platform} devices only")
     cfg, traffic = cell.config, cell.traffic
-    task = tasks.make(cfg["payload"]) if cfg.get("payload") else None
+    inputs = cell.kind.inputs(cfg["payload"]) if cell.kind else None
     protocols = list(dict.fromkeys(s["protocol"] for s in traffic["studies"]))
-    program = Program(cfg, graphs.make(cfg["graph"]), protocols, task)
+    program = Program(cfg, graphs.make(cfg["graph"]), protocols, cell.kind, inputs)
     for st in studies.warmups(traffic, seed):
         program.fetch(program.dispatch(st))
 

@@ -6,18 +6,21 @@ import time
 import pytest
 from tinycells import tiny
 
-from chipbench import faults, run
+from chipbench import faults, run, spec
 
 CELLS = {
-    "paper-regular100.fig1-decafork": faults.NAMES,
-    "paper-regular100.fig1-baseline": faults.NAMES,
-    "rwsgd-regular100.learn": faults.LEARN_NAMES,
+    name: faults.names(spec.resolve(name).kind)
+    for name in (
+        "paper-regular100.fig1-decafork",
+        "paper-regular100.fig1-baseline",
+        "rwsgd-regular100.learn",
+    )
 }
 
 
 def _run(name, fault):
     cell = tiny(name)
-    with faults.planted(cell.config, fault):
+    with faults.planted(cell.kind, fault):
         return run.run(cell, 2**31 + 11, 0.1, False, require_tpu=False, t0=time.perf_counter())
 
 

@@ -3,7 +3,7 @@ lower-precision controls are rejected by the check."""
 import pytest
 from tinycells import tiny
 
-from chipbench import check, graphs, reference, studies, tasks
+from chipbench import check, graphs, reference, studies
 from chipbench.program import Program
 
 SWEEP = "paper-regular100.fig1-decafork"
@@ -14,8 +14,8 @@ SEEDS = 4
 def _study(cell, proto):
     cfg = cell.config
     neighbors = graphs.make(cfg["graph"])
-    task = tasks.make(cfg["payload"]) if cfg.get("payload") else None
-    program = Program(cfg, neighbors, [proto], task)
+    task = cell.kind.inputs(cfg["payload"]) if cell.kind else None
+    program = Program(cfg, neighbors, [proto], cell.kind, task)
     st = studies.Study(0, proto, SEEDS, 2**31 + 17)
     return neighbors, task, st, program.fetch(program.dispatch(st))
 
@@ -30,7 +30,7 @@ def test_reference_replays_the_program_and_rejects_its_control(name, proto):
     neighbors, task, st, host = _study(cell, proto)
     for i in range(SEEDS):
         prog = {k: v[i] for k, v in host.items()}
-        ref = reference.replay(cfg, neighbors, st, i, task)
+        ref = reference.replay(cfg, neighbors, st, i, cell.kind, task)
         nums = check.combine([check.compare_trajectory(prog, ref)])
         assert nums["mismatch_rounds"] == 0
         assert nums["compared_rounds"] == cfg["steps"] or nums["ties"] == 1
@@ -43,8 +43,8 @@ def test_reference_replays_the_program_and_rejects_its_control(name, proto):
     for precision in ("bfloat16", "bfloat16-payload") if task is not None else ("bfloat16",):
         ctl = [
             check.compare_trajectory(
-                reference.replay(cfg, neighbors, st, i, task, precision=precision),
-                reference.replay(cfg, neighbors, st, i, task),
+                reference.replay(cfg, neighbors, st, i, cell.kind, task, precision=precision),
+                reference.replay(cfg, neighbors, st, i, cell.kind, task),
             )
             for i in range(SEEDS)
         ]
